@@ -30,6 +30,7 @@ from repro.models import ModelConfig
 from repro.models.model import init_params
 from repro.optim import AdamWConfig, init_opt_state
 from repro.data import DataConfig, TokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.train import make_train_step
 
 cfg = ModelConfig(name='bench', num_layers=4, d_model=64, num_heads=4,
@@ -49,7 +50,7 @@ leaves = jax.tree.leaves(params)
 n_elems = sum(l.size for l in leaves)
 n_leaves = len(leaves)
 
-mesh = jax.make_mesh((2, 2), ('pod', 'data'))
+mesh = make_mesh((2, 2), ('pod', 'data'))
 out = {'n_elems': int(n_elems), 'n_leaves': int(n_leaves),
        'params_m': float(n_elems / 1e6),
        'wire_exact': int(EXACT_BYTES_PER_ELEM * n_elems),

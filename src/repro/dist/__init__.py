@@ -43,72 +43,14 @@ existing shard boundaries and moves zero bytes.
 
 from __future__ import annotations
 
-import contextlib
-
-
-def _install_jax_compat() -> None:
-    """Backfill `jax.shard_map` / `jax.set_mesh` on older jax (< 0.5).
-
-    The distribution layer (and its tests) use the modern spellings; on the
-    pinned jax 0.4.x toolchain they map 1:1 onto
-    ``jax.experimental.shard_map.shard_map`` (``axis_names`` → the complement
-    of ``auto``, ``check_vma`` → ``check_rep``) and the ``Mesh`` context
-    manager.  No-op on jax versions that already provide them.
-    """
-    import jax
-
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                      check_vma=None, check_rep=None, auto=None):
-            if auto is None:
-                auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-                        if axis_names is not None else frozenset())
-            if check_rep is None:
-                check_rep = bool(check_vma) if check_vma is not None else True
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_rep,
-                              auto=frozenset(auto))
-
-        jax.shard_map = shard_map
-
-    if not hasattr(jax, "set_mesh"):
-        @contextlib.contextmanager
-        def set_mesh(mesh):
-            with mesh:
-                yield mesh
-
-        jax.set_mesh = set_mesh
-
-    # optimization_barrier has no vmap batching rule on the pinned jax —
-    # the barrier is elementwise-identity, so batching is a pass-through
-    # (needed by the trainer's vmap-over-pods gradient computation, which
-    # maps the model's scan-over-layers residual barriers).
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-
-        if optimization_barrier_p not in batching.primitive_batchers:
-            def _opt_barrier_batcher(args, dims):
-                return optimization_barrier_p.bind(*args), dims
-
-            batching.primitive_batchers[optimization_barrier_p] = \
-                _opt_barrier_batcher
-    except ImportError:  # newer jax: private path moved AND rule exists
-        pass
-
-
-_install_jax_compat()
-
-from repro.dist.compression import (  # noqa: E402
+from repro.dist.compression import (
     compressed_psum_mean,
     init_residual,
     psum_mean,
     reshard_residual,
 )
-from repro.dist.hints import current_policy, shard_hint, sharding_policy  # noqa: E402
-from repro.dist.sharding import (  # noqa: E402
+from repro.dist.hints import current_policy, shard_hint, sharding_policy
+from repro.dist.sharding import (
     MeshAxes,
     activation_hint_policy,
     batch_pspec,

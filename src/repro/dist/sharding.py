@@ -29,8 +29,6 @@ from __future__ import annotations
 
 import dataclasses
 
-import numpy as np
-
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.tree_util import tree_map_with_path
@@ -76,11 +74,8 @@ def reshard_tree(tree, new_shardings, *, old_shardings=None):
     ``old_shardings``, when given, marks leaves whose placement is already
     correct (``old == new``) so their transfer is skipped.
 
-    A leaf whose source and target shardings live on different device sets
-    (migrating a replica between disjoint mesh slices) falls back to a host
-    round-trip: not every supported jax version can transfer a committed
-    array directly across meshes, and the values are bit-identical either
-    way.
+    Leaves move device to device with ``jax.device_put``, also between
+    disjoint mesh slices.
     """
     flat_t, tdef = jax.tree.flatten(tree)
     flat_new = tdef.flatten_up_to(new_shardings)
@@ -90,10 +85,7 @@ def reshard_tree(tree, new_shardings, *, old_shardings=None):
     def place(x, new, old):
         if new is None or (old is not None and old == new):
             return x
-        try:
-            return jax.device_put(x, new)
-        except (ValueError, RuntimeError):
-            return jax.device_put(np.asarray(x), new)
+        return jax.device_put(x, new)
 
     return tdef.unflatten(
         [place(x, n, o) for x, n, o in zip(flat_t, flat_new, flat_old)])

@@ -12,15 +12,32 @@ import math
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.dist.sharding import MeshAxes
+
+
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """The one mesh constructor: every axis ``Auto``.
+
+    The sharding rules (``repro.dist``) place values with
+    ``with_sharding_constraint`` hints that GSPMD may propagate around.
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, on which those hints
+    become asserts and gathers demand an ``out_sharding``; so no caller
+    builds a mesh with it directly.  ``devices`` pins an explicit device
+    list (len == prod(shape)) in row-major order.
+    """
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
+    devs = np.asarray(devices, dtype=object).reshape(tuple(shape))
+    return Mesh(devs, tuple(axes), axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_axes(*, multi_pod: bool = False) -> MeshAxes:
@@ -33,10 +50,7 @@ def make_debug_mesh(shape=(2, 2), axes=("data", "model"), devices=None):
     ``devices`` pins an explicit device list (len == prod(shape)) — the
     building block for carving one host's pool into disjoint replica slices.
     """
-    if devices is None:
-        return jax.make_mesh(shape, axes)
-    devs = np.asarray(devices, dtype=object).reshape(shape)
-    return Mesh(devs, axes)
+    return make_mesh(shape, axes, devices)
 
 
 def slice_device_pool(shapes, axes=("data", "model"), devices=None, *,

@@ -4,13 +4,16 @@
   PYTHONPATH=src python -m repro.launch.serve --paged      # continuous batching
   PYTHONPATH=src python -m repro.launch.serve --sharded    # mesh-backed fleet
   PYTHONPATH=src python -m repro.launch.serve --trace /tmp/serve_trace.json
+  PYTHONPATH=src python -m repro.launch.serve --full --num-layers 16 \
+      --paged --fused-scheduler --max-len 1024   # published widths (TPU)
 
 ``--paged`` serves through the block-paged KV pool (``serve/paging.py``):
 requests are HEFT_RT-mapped and then *admitted into the running batch* at
 each decode tick (``--max-batch`` slots, ``--page-size``-token pages;
 ``--num-pages`` below full occupancy exercises admission queueing), and
-request 0 is verified token-identical to the dense oracle.  See
-docs/serving.md for the design.
+request 0 is checked against the model's full forward pass: each served
+token's logit within ``ORACLE_TOL_ULPS`` of the reference's top logit
+(see ``check_against_reference``).  See docs/serving.md for the design.
 
 Default mode builds a small heterogeneous "fleet" of replicas of a
 smoke-config model (speed factors emulate mixed pods).  ``--sharded`` carves
@@ -47,12 +50,17 @@ Chrome trace with the metrics snapshot embedded.  Output verbosity is the
 from __future__ import annotations
 
 import argparse
+import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_smoke_config
-from repro.models.model import init_params
+from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.config import ModelConfig
+from repro.models.layers import dtype_of
+from repro.models.model import init_params_on, logits_fn
 from repro.obs import MetricsRegistry, Tracer, get_logger
 from repro.obs.metrics import time_s
 from repro.serve import HeftFrontEnd, ReplicaHandle, ServeEngine, mesh_backed_fleet
@@ -60,17 +68,29 @@ from repro.serve import HeftFrontEnd, ReplicaHandle, ServeEngine, mesh_backed_fl
 log = get_logger("serve")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-7b")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the architecture's published config "
+                         "(get_config) instead of its smoke preset")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="with --full: cut depth to this many layers (a "
+                         "whole number of layer periods); widths are kept")
+    ap.add_argument("--max-len", type=int, default=128,
+                    help="per-request cache length (prompt + new tokens)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=16)
+    ap.add_argument("--prompt-lens", default=None, metavar="N,M,...",
+                    help="prompt lengths, cycled over the requests "
+                         "(default: uniform in [8, 48)); each distinct "
+                         "length compiles its own prefill")
     ap.add_argument("--replicas", type=int, default=3)
     ap.add_argument("--paged", action="store_true",
                     help="continuous batching: serve through the block-paged "
                          "KV pool (ServeEngine.admit/decode_tick/retire; "
-                         "see docs/serving.md), verifying request 0 "
-                         "token-identical to the dense oracle")
+                         "see docs/serving.md), checking request 0 "
+                         "against the full-forward reference")
     ap.add_argument("--max-batch", type=int, default=4,
                     help="with --paged: concurrent batch slots per replica")
     ap.add_argument("--page-size", type=int, default=16,
@@ -108,12 +128,113 @@ def main() -> None:
                          "failure-free run (default 90)")
     ap.add_argument("--slo-s", type=float, default=2.0,
                     help="per-request latency SLO for the goodput metric")
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_smoke_config(args.arch)
-    params = init_params(jax.random.key(0), cfg)
-    log.info(f"arch={cfg.name} params={cfg.param_count()/1e6:.2f}M "
-             f"devices={jax.device_count()}")
+
+def served_config(args) -> ModelConfig:
+    """The smoke preset, or with ``--full`` the published config cut only
+    in depth (``--num-layers``); every width stays as published."""
+    if not args.full:
+        if args.num_layers is not None:
+            raise SystemExit("--num-layers requires --full")
+        return get_smoke_config(args.arch)
+    cfg = get_config(args.arch)
+    if args.num_layers is not None:
+        n, first, period = args.num_layers, cfg.first_dense_layers, cfg.period
+        if not (first < n <= cfg.num_layers and (n - first) % period == 0):
+            raise SystemExit(
+                f"--num-layers must be {first} plus a positive multiple of "
+                f"{cfg.name}'s layer period {period}, at most "
+                f"{cfg.num_layers}")
+        cfg = cfg.with_(num_layers=n)
+    return cfg
+
+
+def make_requests(args, vocab_size: int) -> list[tuple[np.ndarray, int]]:
+    rng = np.random.default_rng(0)
+    lens = ([int(n) for n in args.prompt_lens.split(",")]
+            if args.prompt_lens else None)
+    return [
+        (rng.integers(0, vocab_size,
+                      lens[i % len(lens)] if lens else rng.integers(8, 48)
+                      ).astype(np.int32), args.new_tokens)
+        for i in range(args.requests)
+    ]
+
+
+# The oracle check's tolerance, in units in the last place (ulps) of the
+# config's compute dtype at the magnitude of the top reference logit.  The
+# paged tick (a batch of lanes at their own positions, attention over a
+# gathered page view) and the reference (one causal forward pass over the
+# whole sequence) are different programs: their reductions run in another
+# order and each rounds its logits to the compute dtype, so a near-tie can
+# flip between them.  On a TPU v5e at bf16 and deepseek-7b widths the
+# largest gap measured was 2 ulps (PERF.md).  A wrong computation misses by a top-2 logit gap,
+# several ulps at random weights, and does so at many of its steps; a path
+# that computes in a lower precision than the config states misses by that
+# precision's error, which is many ulps of the stated dtype.
+ORACLE_TOL_ULPS = 4
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _reference_top_and_picked(params, tokens, targets, cfg):
+    """Per position of the full forward pass over ``tokens`` (1, S): the top
+    logit and the logit of ``targets`` (S,), in float32.  Only these two
+    rows leave the device, never the (S, vocab) logits."""
+    logits = logits_fn(params, tokens, cfg, remat=False)[0][0]
+    logits = logits.astype(jnp.float32)
+    picked = jnp.take_along_axis(logits, targets[:, None], axis=1)[:, 0]
+    return logits.max(axis=1), picked
+
+
+def reference_gaps(cfg: ModelConfig, engine, prompt: np.ndarray,
+                   served: np.ndarray) -> np.ndarray:
+    """Score a served sequence against the model's full forward pass.
+
+    For each generated token: how far its logit lies below the top logit of
+    the reference row that predicts it, in ulps of ``cfg``'s compute dtype
+    at that top logit (0 where the served token is the reference argmax).
+    The reference runs ``cfg`` — the config as stated, not the engine's —
+    over the served prefix (teacher-forced) on ``engine``'s weights and
+    placement.
+    """
+    with engine._ctx():
+        top, picked = _reference_top_and_picked(
+            engine.params, jnp.asarray(served[None, :-1]),
+            jnp.asarray(served[1:]), cfg)
+    top, picked = (np.asarray(x)[len(prompt) - 1:] for x in (top, picked))
+    eps = float(jnp.finfo(dtype_of(cfg.compute_dtype)).eps)
+    ulp = eps * np.exp2(np.floor(np.log2(np.maximum(np.abs(top), 1e-30))))
+    return (top - picked) / ulp
+
+
+def check_against_reference(cfg: ModelConfig, engine, prompt: np.ndarray,
+                            served: np.ndarray) -> tuple[float, str | None]:
+    """(largest gap in ulps, None) when every served token is within
+    ``ORACLE_TOL_ULPS`` of the reference argmax; otherwise the gap and a
+    description of the first step that is not."""
+    gaps = reference_gaps(cfg, engine, prompt, served)
+    worst = float(gaps.max())
+    bad = np.flatnonzero(gaps > ORACLE_TOL_ULPS)
+    if not len(bad):
+        return worst, None
+    k = int(bad[0])
+    return worst, (f"step {k} (position {len(prompt) + k}): served token "
+                   f"{int(served[len(prompt) + k])} is {gaps[k]:.1f} ulps "
+                   f"below the reference's top logit (tolerance "
+                   f"{ORACLE_TOL_ULPS})")
+
+
+def main(argv=None) -> dict:
+    """Run the demo; returns what it served, for callers that check it
+    (``chip_smoke.py``)."""
+    args = build_parser().parse_args(argv)
+
+    cfg = served_config(args)
+    key = jax.random.key(0)
+    log.info(f"arch={cfg.name} layers={cfg.num_layers} d_model={cfg.d_model} "
+             f"params={cfg.param_count()/1e6:.2f}M {cfg.param_dtype} "
+             f"max_len={args.max_len} devices={jax.device_count()}")
 
     tracer, metrics = (Tracer(), MetricsRegistry()) if args.trace else (None, None)
 
@@ -121,14 +242,20 @@ def main() -> None:
     if args.sharded:
         shapes = [tuple(int(d) for d in s.split("x"))
                   for s in args.mesh_shapes.split(",")]
-        fleet, spare = mesh_backed_fleet(cfg, params, shapes, max_len=128,
+        # Each slice initialises its own weights from the key, born under
+        # its own shardings: nothing is built whole on one device first.
+        fleet, spare = mesh_backed_fleet(cfg, key, shapes,
+                                         max_len=args.max_len,
                                          return_spare=True)
         log.info(f"mesh-backed fleet: {[r.mesh_shape for r in fleet]} slices "
                  f"({len(spare)} spare devices)")
     else:
+        # One weight tree shared by every replica of the fleet.
+        params = init_params_on(key, cfg)
         speeds = [1.0, 0.7, 1.4][: args.replicas] or [1.0]
         fleet = [ReplicaHandle(f"replica{i}(x{s})",
-                               ServeEngine(cfg, params, max_len=128), speed=s)
+                               ServeEngine(cfg, params, max_len=args.max_len),
+                               speed=s)
                  for i, s in enumerate(speeds)]
 
     fabric = None
@@ -154,12 +281,9 @@ def main() -> None:
                 r.engine.tracer = tracer
     front = HeftFrontEnd(fleet, fabric=fabric, tracer=tracer, metrics=metrics)
 
-    rng = np.random.default_rng(0)
-    requests = [
-        (rng.integers(0, cfg.vocab_size, rng.integers(8, 48)).astype(np.int32),
-         args.new_tokens)
-        for _ in range(args.requests)
-    ]
+    requests = make_requests(args, cfg.vocab_size)
+    result = {"cfg": cfg, "front": front, "fabric": fabric,
+              "requests": requests}
     if args.paged:
         # Continuous batching: requests join/leave the running batch at the
         # admission tick instead of queueing behind whole generations.
@@ -183,15 +307,19 @@ def main() -> None:
             log.info(f"scheduling decisions: {stats['fused_decisions']} "
                      f"fused in-tick, {stats['host_decisions']} host "
                      f"(cold-start/idle)")
-        oracle = front.replicas[0].engine.generate(requests[0][0][None, :],
-                                                   requests[0][1])
-        if not np.array_equal(outs[0], oracle):
-            raise SystemExit("paged output diverged from the dense oracle")
-        log.info("request 0 verified token-identical to the dense oracle")
+        result["stats"] = stats
+        worst, diverged = check_against_reference(
+            cfg, front.replicas[0].engine, requests[0][0], seqs[0])
+        if diverged is not None:
+            raise SystemExit("paged output disagrees with the full-forward "
+                             "reference: " + diverged)
+        log.info(f"request 0 verified against the full-forward reference "
+                 f"(largest gap {worst:.1f} of {ORACLE_TOL_ULPS} ulps)")
     else:
         (outs, counts), dt = time_s(front.run_batch, requests)
         log.info(f"{len(outs)} requests in {dt:.2f}s "
                  f"({sum(len(p)+args.new_tokens for p,_ in requests)/dt:.0f} tok/s)")
+    result["outputs"] = outs
     log.info(f"request distribution (HEFT_RT): {counts}")
     log.info(f"sample output ids: {outs[0][0, -8:].tolist()}")
 
@@ -230,6 +358,7 @@ def main() -> None:
         tracer.export(args.trace, metrics=metrics)
         log.info(f"trace: {args.trace} ({len(tracer)} events, "
                  f"{len(metrics)} metrics)")
+    return result
 
 
 def _resolve_targets(timeline, names):
@@ -317,4 +446,5 @@ def _run_chaos(args, front, requests, outs, tracer, metrics) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -23,6 +23,7 @@ import argparse
 
 from repro.configs import get_smoke_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import MetricsRegistry, Tracer, get_logger
 from repro.obs.metrics import time_s
 from repro.optim import AdamWConfig, warmup_cosine
@@ -88,4 +89,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,8 @@ Design notes
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -99,7 +101,16 @@ def chunked_causal_attention(
             q_chunk = S
     qc = min(q_chunk, S)
     kc = min(kv_chunk, S)
-    assert S % qc == 0 and S % kc == 0, (S, qc, kc)
+    if S % qc or S % kc:
+        # Pad to whole chunks and drop the padded rows: every padded key
+        # lies after every real query, so the causal mask hides it.
+        step = math.lcm(qc, kc)
+        pad = ((0, 0), (0, -S % step), (0, 0), (0, 0))
+        out = chunked_causal_attention(
+            jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad), scale=scale,
+            attn_cap=attn_cap, window=window, q_chunk=qc, kv_chunk=kc,
+            differentiable=differentiable)
+        return out[:, :S]
     nq = S // qc
     nk = S // kc
 

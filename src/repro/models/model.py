@@ -56,6 +56,16 @@ def init_params(key, cfg: ModelConfig) -> dict:
     return params
 
 
+def init_params_on(key, cfg: ModelConfig, shardings=None) -> dict:
+    """:func:`init_params` as one compiled program whose outputs are born
+    in ``shardings`` (a pytree of shardings, or None for the default
+    device).  Run eagerly, the init holds each leaf's float32 draw before
+    the cast and builds everything on one device; at a 7B model's widths
+    that alone can exceed a chip."""
+    return jax.jit(init_params, static_argnums=1,
+                   out_shardings=shardings)(key, cfg)
+
+
 def param_shapes(cfg: ModelConfig):
     """Shape pytree without allocating (drives param_count + checkpoints)."""
     shapes = jax.eval_shape(

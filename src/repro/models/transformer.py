@@ -29,25 +29,6 @@ from repro.models.layers import rms_norm
 from repro.models.moe import init_moe_params, moe_block
 
 
-@jax.custom_vjp
-def _residual_barrier(x):
-    """`optimization_barrier` with an explicit VJP (the primitive has no
-    differentiation rule on the pinned jax); the cotangent is barriered too,
-    so the backward residual stream gets the same hoisting protection."""
-    return lax.optimization_barrier(x)
-
-
-def _residual_barrier_fwd(x):
-    return lax.optimization_barrier(x), None
-
-
-def _residual_barrier_bwd(_, g):
-    return (lax.optimization_barrier(g),)
-
-
-_residual_barrier.defvjp(_residual_barrier_fwd, _residual_barrier_bwd)
-
-
 def _sublayer_plan(cfg: ModelConfig) -> list[dict]:
     """Static description of each sub-layer slot within a stage."""
     plan = []
@@ -191,8 +172,9 @@ def apply_stack(
         x, agg = carry
         # barrier: keeps the saved-for-backward residual in its storage dtype
         # (XLA otherwise hoists downstream f32 converts into the save loop,
-        # doubling the stacked-residual footprint).
-        x = _residual_barrier(x)
+        # doubling the stacked-residual footprint).  Its VJP barriers the
+        # cotangent too, protecting the backward residual stream.
+        x = lax.optimization_barrier(x)
         sp, c = stage_in
         new_cache = {}
         for j, slot in enumerate(plan):

@@ -54,13 +54,18 @@ from repro.core import heft_rt_numpy
 from repro.dist.hints import sharding_policy
 from repro.dist.sharding import MeshAxes, named, replica_pspecs, reshard_tree
 from repro.models.config import ModelConfig
-from repro.models.model import decode_step, prefill_step
+from repro.models.model import decode_step, init_params_on, prefill_step
 from repro.obs.metrics import Stopwatch
 
 
 def _host_scale_s(prompt_tokens, new_tokens):
     """The abstract-fleet service-time estimate (seconds, elementwise)."""
     return 1e-4 * prompt_tokens + 2e-3 * new_tokens
+
+
+def _is_key(x) -> bool:
+    return (isinstance(x, jax.Array)
+            and jnp.issubdtype(x.dtype, jax.dtypes.prng_key))
 
 
 def _span(tracer, name, **args):
@@ -78,6 +83,10 @@ class ServeEngine:
     device_put to their FSDP+TP layout once, caches live sharded across the
     slice (KV heads over ``model``), and every step traces under
     ``jax.set_mesh`` + the replica's activation ``sharding_policy``.
+
+    ``params`` may also be a PRNG key: the engine then initialises random
+    weights from it directly in their final placement (the slice's
+    layout, or the default device unmeshed).
     """
 
     cfg: ModelConfig
@@ -105,7 +114,9 @@ class ServeEngine:
             self._policy = dict(specs["policy"], __mesh__=self.mesh)
             self._cache_sh = c_sh
             with self._ctx():
-                self.params = reshard_tree(self.params, p_sh)
+                self.params = (init_params_on(self.params, self.cfg, p_sh)
+                               if _is_key(self.params)
+                               else reshard_tree(self.params, p_sh))
             self._decode = jax.jit(
                 lambda p, c, t, pos: decode_step(p, c, t, pos, self.cfg),
                 in_shardings=(p_sh, c_sh, b_sh, None),
@@ -116,6 +127,8 @@ class ServeEngine:
         else:
             self._policy = None
             self._cache_sh = None
+            if _is_key(self.params):
+                self.params = init_params_on(self.params, self.cfg)
             self._decode = jax.jit(
                 lambda p, c, t, pos: decode_step(p, c, t, pos, self.cfg))
             self._prefill = jax.jit(
@@ -703,7 +716,7 @@ class HeftFrontEnd:
         return [outputs[i] for i in range(len(requests))], stats
 
 
-def mesh_backed_fleet(cfg: ModelConfig, params: dict, mesh_shapes,
+def mesh_backed_fleet(cfg: ModelConfig, params, mesh_shapes,
                       *, max_len: int = 128, arch: str | None = None,
                       axes: MeshAxes | None = None, devices=None,
                       chip_tflops: float = 1.0, chip_hbm_gbps: float = 1.0,
@@ -715,7 +728,8 @@ def mesh_backed_fleet(cfg: ModelConfig, params: dict, mesh_shapes,
     aggregate rates (and HEFT_RT speed fallback) scale with slice size.
     ``return_spare=True`` additionally returns the pool's uncarved devices
     (``slice_device_pool``'s remainder) — the spare budget elastic resize
-    events re-carve later.
+    events re-carve later.  ``params`` is a weight tree, or a PRNG key from
+    which each slice initialises its weights in its own layout.
     """
     from repro.launch.mesh import slice_device_pool
 

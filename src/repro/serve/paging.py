@@ -86,6 +86,28 @@ def _leaf_kind(path) -> tuple[bool, bool]:
     raise ValueError(f"unknown cache leaf {'/'.join(keys)!r}")
 
 
+def pool_shapes(cfg, num_pages: int, page_size: int, max_batch: int,
+                max_len: int):
+    """ShapeDtypeStruct tree of a page pool, mirroring ``model.cache_specs``
+    leaf-for-leaf: paged leaves hold ``num_pages + 1`` pages of
+    ``page_size`` tokens, state leaves ``max_batch + 1`` slots (the last
+    page / slot is the scratch one)."""
+    from repro.models.model import cache_specs
+
+    def pool_spec(path, leaf):
+        paged, stacked = _leaf_kind(path)
+        shape = list(leaf.shape)
+        b_ax, s_ax = (1, 2) if stacked else (0, 1)
+        if paged:
+            shape[b_ax] = num_pages + 1
+            shape[s_ax] = page_size
+        else:
+            shape[b_ax] = max_batch + 1
+        return jax.ShapeDtypeStruct(tuple(shape), leaf.dtype)
+
+    return tree_map_with_path(pool_spec, cache_specs(cfg, 1, max_len))
+
+
 @dataclass
 class _Slot:
     """Host-side decode state of one in-flight request."""
@@ -117,7 +139,7 @@ class PagePool:
     """
 
     def __init__(self, cfg, max_batch: int, page_size: int, max_len: int,
-                 num_pages: int | None = None):
+                 num_pages: int | None = None, shardings=None):
         if max_len % page_size != 0:
             raise ValueError(
                 f"max_len={max_len} must be a multiple of page_size={page_size}")
@@ -141,26 +163,15 @@ class PagePool:
         self.free_slot_ids: deque[int] = deque(range(self.max_batch))
         self.allocated = 0
         self.freed = 0
-        self.pools = self._init_pools()
+        self.pools = self._init_pools(shardings)
 
-    def _init_pools(self):
-        """Zero pool tree mirroring ``model.cache_specs`` leaf-for-leaf."""
-        from repro.models.model import cache_specs
-
-        specs = cache_specs(self.cfg, 1, self.max_len)
-
-        def pool_spec(path, leaf):
-            paged, stacked = _leaf_kind(path)
-            shape = list(leaf.shape)
-            b_ax, s_ax = (1, 2) if stacked else (0, 1)
-            if paged:
-                shape[b_ax] = self.num_pages + 1
-                shape[s_ax] = self.page_size
-            else:
-                shape[b_ax] = self.max_batch + 1
-            return jnp.zeros(tuple(shape), leaf.dtype)
-
-        return tree_map_with_path(pool_spec, specs)
+    def _init_pools(self, shardings=None):
+        """Zero pool tree born in ``shardings`` (None: the default device)."""
+        shapes = pool_shapes(self.cfg, self.num_pages, self.page_size,
+                             self.max_batch, self.max_len)
+        return jax.jit(lambda: jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), shapes),
+            out_shardings=shardings)()
 
     # -- allocation ---------------------------------------------------------
 
@@ -203,6 +214,133 @@ class PagePool:
         return len(self.free_slot_ids)
 
 
+def paged_programs(cfg, page_size: int, pages_per_slot: int) -> dict:
+    """The paged runtime's device programs as plain (unjitted) functions.
+
+    ``tick``, ``tick_sched``, ``tick_sched_counted``, ``admit_scatter`` and
+    ``restore_scatter`` over pool trees of ``page_size``-token pages,
+    ``pages_per_slot`` per sequence.  :class:`PagedRuntime` jits them for
+    its engine's placement; a compile-only check can lower them from
+    abstract shapes for a device that is not attached.
+    """
+    from repro.models.model import decode_step
+
+    pp, ps = pages_per_slot, page_size
+
+    def gather(pools, table, slot_ids):
+        """pools + (B, pp) table + (B,) slot ids → dense (B, Smax, ...)
+        cache view."""
+        B = table.shape[0]
+
+        def g(path, pool):
+            paged, stacked = _leaf_kind(path)
+            if paged:
+                if stacked:
+                    v = pool[:, table]          # (L, B, pp, ps, ...)
+                    return v.reshape(v.shape[0], B, pp * ps,
+                                     *v.shape[4:])
+                v = pool[table]                 # (B, pp, ps, ...)
+                return v.reshape(B, pp * ps, *v.shape[3:])
+            return pool[:, slot_ids] if stacked else pool[slot_ids]
+
+        return tree_map_with_path(g, pools)
+
+    def scatter_token(pools, new_caches, table, slot_ids, pos):
+        """Write back only what the tick changed: the one token each lane
+        wrote at ``pos`` (paged leaves) and the rolled state rows."""
+        B = table.shape[0]
+        rows = jnp.arange(B)
+        page = table[rows, pos // ps]           # (B,) target page ids
+        off = pos % ps
+
+        def s(path, pool, new):
+            paged, stacked = _leaf_kind(path)
+            if paged:
+                if stacked:
+                    return pool.at[:, page, off].set(new[:, rows, pos])
+                return pool.at[page, off].set(new[rows, pos])
+            if stacked:
+                return pool.at[:, slot_ids].set(new)
+            return pool.at[slot_ids].set(new)
+
+        return tree_map_with_path(s, pools, new_caches)
+
+    def tick(params, pools, table, slot_ids, pos, tok):
+        dense = gather(pools, table, slot_ids)
+        logits, new_caches = decode_step(params, dense, tok, pos, cfg)
+        pools = scatter_token(pools, new_caches, table, slot_ids, pos)
+        # Greedy selection INSIDE the jitted program: the host only ever
+        # transfers the (B,) winning tokens, never the (B, V) logits —
+        # same argmax the dense oracle computes, one op earlier
+        # (host-sync-in-hot-path design rule; see repro.analysis).
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pools
+
+    # The fused-scheduler tick: the HEFT_RT decision for the next
+    # admission batch runs INSIDE the same compiled program as the
+    # decode step, against the fabric's device-resident T_avail/mask
+    # registers (docs/scheduling.md).  Decode math is byte-for-byte the
+    # plain tick's; the decision outputs ride the token transfer the
+    # tick already makes, so steady-state serving schedules with zero
+    # extra host round-trips.
+    def tick_sched(params, pools, table, slot_ids, pos, tok,
+                   a_p, ex_p, valid, avail, mask):
+        toks, pools = tick(params, pools, table, slot_ids, pos, tok)
+        res = decision_ref(a_p, ex_p, avail, valid, mask)
+        # Tokens + decision leave the device as ONE packed int32 buffer
+        # (see pack_tick_outputs): per-output host syncs would cost more
+        # than the decision itself.  new_avail additionally rides out as
+        # the live register (donated buffer), never materialized.
+        return pack_tick_outputs(toks, res), pools, res.new_avail
+
+    def tick_sched_counted(params, pools, table, slot_ids, pos, tok,
+                           a_p, ex_p, valid, avail, mask, counters,
+                           p_valid):
+        toks, pools = tick(params, pools, table, slot_ids, pos, tok)
+        res = decision_ref(a_p, ex_p, avail, valid, mask)
+        counters = accumulate_counters(counters, res.assignment,
+                                       res.new_avail, valid, p_valid)
+        return pack_tick_outputs(toks, res), pools, res.new_avail, counters
+
+    def admit_scatter(pools, dense, table_row, slot):
+        """Place one request's freshly prefilled (B=1) dense cache into
+        its reserved pages / state slot.  Tail table entries are the
+        scratch page, so over-length writes land there harmlessly."""
+
+        def s(path, pool, d):
+            paged, stacked = _leaf_kind(path)
+            if paged:
+                if stacked:
+                    v = d[:, 0].reshape(d.shape[0], pp, ps, *d.shape[3:])
+                    return pool.at[:, table_row].set(v)
+                v = d[0].reshape(pp, ps, *d.shape[2:])
+                return pool.at[table_row].set(v)
+            if stacked:
+                return pool.at[:, slot].set(d[:, 0])
+            return pool.at[slot].set(d[0])
+
+        return tree_map_with_path(s, pools, dense)
+
+    def restore_scatter(pools, vals, table_row, slot):
+        """Place a snapshotted page set (already page-shaped) back."""
+
+        def s(path, pool, v):
+            paged, stacked = _leaf_kind(path)
+            if paged:
+                if stacked:
+                    return pool.at[:, table_row].set(v)
+                return pool.at[table_row].set(v)
+            if stacked:
+                return pool.at[:, slot].set(v)
+            return pool.at[slot].set(v)
+
+        return tree_map_with_path(s, pools, vals)
+
+    return {"tick": tick, "tick_sched": tick_sched,
+            "tick_sched_counted": tick_sched_counted,
+            "admit_scatter": admit_scatter,
+            "restore_scatter": restore_scatter}
+
+
 class PagedRuntime:
     """Continuous-batching decode runtime bound to one ``ServeEngine``.
 
@@ -217,176 +355,76 @@ class PagedRuntime:
                  num_pages: int | None = None):
         self.engine = engine
         self.pool = PagePool(engine.cfg, max_batch, page_size, engine.max_len,
-                             num_pages=num_pages)
+                             num_pages=num_pages,
+                             shardings=self._pool_shardings())
         self.slots: dict[int, _Slot] = {}
         self._bind()
 
     # -- compiled steps (rebuilt on reshard) --------------------------------
 
+    def _pool_shardings(self):
+        """``page_pspecs`` layouts on the engine's mesh (None unmeshed)."""
+        from repro.dist.sharding import named, page_pspecs
+
+        eng = self.engine
+        if eng.mesh is None:
+            return None
+        return named(eng.mesh, page_pspecs(eng.cfg, eng.axes))
+
     def _bind(self) -> None:
         """(Re)build the jitted tick/admit-scatter for the engine's current
         mesh slice.  Mirrors ``ServeEngine._build``: pool leaves take the
         ``page_pspecs`` layouts, everything else replicates."""
-        from repro.dist.sharding import (named, page_pspecs, replica_pspecs,
-                                         reshard_tree)
-        from repro.models.model import decode_step
+        from repro.dist.sharding import named, replica_pspecs, reshard_tree
 
         eng = self.engine
         cfg = eng.cfg
-        pp, ps = self.pool.pages_per_slot, self.pool.page_size
-        scratch_page = self.pool.scratch_page
-
-        def gather(pools, table, slot_ids):
-            """pools + (B, pp) table + (B,) slot ids → dense (B, Smax, ...)
-            cache view."""
-            B = table.shape[0]
-
-            def g(path, pool):
-                paged, stacked = _leaf_kind(path)
-                if paged:
-                    if stacked:
-                        v = pool[:, table]          # (L, B, pp, ps, ...)
-                        return v.reshape(v.shape[0], B, pp * ps,
-                                         *v.shape[4:])
-                    v = pool[table]                 # (B, pp, ps, ...)
-                    return v.reshape(B, pp * ps, *v.shape[3:])
-                return pool[:, slot_ids] if stacked else pool[slot_ids]
-
-            return tree_map_with_path(g, pools)
-
-        def scatter_token(pools, new_caches, table, slot_ids, pos):
-            """Write back only what the tick changed: the one token each lane
-            wrote at ``pos`` (paged leaves) and the rolled state rows."""
-            B = table.shape[0]
-            rows = jnp.arange(B)
-            page = table[rows, pos // ps]           # (B,) target page ids
-            off = pos % ps
-
-            def s(path, pool, new):
-                paged, stacked = _leaf_kind(path)
-                if paged:
-                    if stacked:
-                        return pool.at[:, page, off].set(new[:, rows, pos])
-                    return pool.at[page, off].set(new[rows, pos])
-                if stacked:
-                    return pool.at[:, slot_ids].set(new)
-                return pool.at[slot_ids].set(new)
-
-            return tree_map_with_path(s, pools, new_caches)
-
-        def tick(params, pools, table, slot_ids, pos, tok):
-            dense = gather(pools, table, slot_ids)
-            logits, new_caches = decode_step(params, dense, tok, pos, cfg)
-            pools = scatter_token(pools, new_caches, table, slot_ids, pos)
-            # Greedy selection INSIDE the jitted program: the host only ever
-            # transfers the (B,) winning tokens, never the (B, V) logits —
-            # same argmax the dense oracle computes, one op earlier
-            # (host-sync-in-hot-path design rule; see repro.analysis).
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), pools
-
-        # The fused-scheduler tick: the HEFT_RT decision for the next
-        # admission batch runs INSIDE the same compiled program as the
-        # decode step, against the fabric's device-resident T_avail/mask
-        # registers (docs/scheduling.md).  Decode math is byte-for-byte the
-        # plain tick's; the decision outputs ride the token transfer the
-        # tick already makes, so steady-state serving schedules with zero
-        # extra host round-trips.
-        def tick_sched(params, pools, table, slot_ids, pos, tok,
-                       a_p, ex_p, valid, avail, mask):
-            toks, pools = tick(params, pools, table, slot_ids, pos, tok)
-            res = decision_ref(a_p, ex_p, avail, valid, mask)
-            # Tokens + decision leave the device as ONE packed int32 buffer
-            # (see pack_tick_outputs): per-output host syncs would cost more
-            # than the decision itself.  new_avail additionally rides out as
-            # the live register (donated buffer), never materialized.
-            return pack_tick_outputs(toks, res), pools, res.new_avail
-
-        def tick_sched_counted(params, pools, table, slot_ids, pos, tok,
-                               a_p, ex_p, valid, avail, mask, counters,
-                               p_valid):
-            toks, pools = tick(params, pools, table, slot_ids, pos, tok)
-            res = decision_ref(a_p, ex_p, avail, valid, mask)
-            counters = accumulate_counters(counters, res.assignment,
-                                           res.new_avail, valid, p_valid)
-            return pack_tick_outputs(toks, res), pools, res.new_avail, counters
-
-        def admit_scatter(pools, dense, table_row, slot):
-            """Place one request's freshly prefilled (B=1) dense cache into
-            its reserved pages / state slot.  Tail table entries are the
-            scratch page, so over-length writes land there harmlessly."""
-
-            def s(path, pool, d):
-                paged, stacked = _leaf_kind(path)
-                if paged:
-                    if stacked:
-                        v = d[:, 0].reshape(d.shape[0], pp, ps, *d.shape[3:])
-                        return pool.at[:, table_row].set(v)
-                    v = d[0].reshape(pp, ps, *d.shape[2:])
-                    return pool.at[table_row].set(v)
-                if stacked:
-                    return pool.at[:, slot].set(d[:, 0])
-                return pool.at[slot].set(d[0])
-
-            return tree_map_with_path(s, pools, dense)
-
-        def restore_scatter(pools, vals, table_row, slot):
-            """Place a snapshotted page set (already page-shaped) back."""
-
-            def s(path, pool, v):
-                paged, stacked = _leaf_kind(path)
-                if paged:
-                    if stacked:
-                        return pool.at[:, table_row].set(v)
-                    return pool.at[table_row].set(v)
-                if stacked:
-                    return pool.at[:, slot].set(v)
-                return pool.at[slot].set(v)
-
-            return tree_map_with_path(s, pools, vals)
+        fns = paged_programs(cfg, self.pool.page_size,
+                             self.pool.pages_per_slot)
 
         if eng.mesh is not None:
-            ax = eng.axes
-            pool_sh = named(eng.mesh, page_pspecs(cfg, ax))
-            p_sh = named(eng.mesh,
-                         replica_pspecs(cfg, ax, fsdp=eng.fsdp)["params"])
+            pool_sh = self._pool_shardings()
+            specs = replica_pspecs(cfg, eng.axes, fsdp=eng.fsdp)
+            p_sh = named(eng.mesh, specs["params"])
             with eng._ctx():
                 self.pool.pools = reshard_tree(self.pool.pools, pool_sh)
             self._tick = jax.jit(
-                tick,
+                fns["tick"],
                 in_shardings=(p_sh, pool_sh, None, None, None, None),
                 out_shardings=(None, pool_sh), donate_argnums=(1,))
             # Scheduler operands replicate; the fabric's T_avail register
             # file (arg 9) and counter file (arg 11) are donated so the
             # registers stay device-resident across ticks.
             self._tick_sched = jax.jit(
-                tick_sched,
+                fns["tick_sched"],
                 in_shardings=(p_sh, pool_sh) + (None,) * 9,
                 out_shardings=(None, pool_sh, None),
                 donate_argnums=(1, 9))
             self._tick_sched_counted = jax.jit(
-                tick_sched_counted,
+                fns["tick_sched_counted"],
                 in_shardings=(p_sh, pool_sh) + (None,) * 11,
                 out_shardings=(None, pool_sh, None, None),
                 donate_argnums=(1, 9, 11))
             self._admit_scatter = jax.jit(
-                admit_scatter,
+                fns["admit_scatter"],
                 in_shardings=(pool_sh, eng._cache_sh, None, None),
                 out_shardings=pool_sh, donate_argnums=(0,))
             self._restore_scatter = jax.jit(
-                restore_scatter,
+                fns["restore_scatter"],
                 in_shardings=(pool_sh, None, None, None),
                 out_shardings=pool_sh, donate_argnums=(0,))
         else:
-            self.pool.pools = jax.tree.map(jnp.asarray, self.pool.pools)
-            self._tick = jax.jit(tick, donate_argnums=(1,))
-            self._tick_sched = jax.jit(tick_sched, donate_argnums=(1, 9))
-            self._tick_sched_counted = jax.jit(tick_sched_counted,
+            self._tick = jax.jit(fns["tick"], donate_argnums=(1,))
+            self._tick_sched = jax.jit(fns["tick_sched"],
+                                       donate_argnums=(1, 9))
+            self._tick_sched_counted = jax.jit(fns["tick_sched_counted"],
                                                donate_argnums=(1, 9, 11))
-            self._admit_scatter = jax.jit(admit_scatter, donate_argnums=(0,))
-            self._restore_scatter = jax.jit(restore_scatter,
+            self._admit_scatter = jax.jit(fns["admit_scatter"],
+                                          donate_argnums=(0,))
+            self._restore_scatter = jax.jit(fns["restore_scatter"],
                                             donate_argnums=(0,))
         # Scratch-page id, exposed for tests/introspection.
-        self.scratch_page = scratch_page
+        self.scratch_page = self.pool.scratch_page
 
     def rebind(self) -> None:
         """Re-place the pools and rebuild the tick after an engine reshard.

@@ -39,6 +39,7 @@ from repro.dist.compression import (
 )
 from repro.dist.hints import sharding_policy
 from repro.dist.sharding import MeshAxes, activation_hint_policy, reshard_tree
+from repro.launch.mesh import make_mesh
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.models.model import init_params, loss_fn
 from repro.optim.adamw import AdamWConfig, adamw_update, init_opt_state
@@ -120,17 +121,13 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, *,
     if pod_axis is None:
         return plain_step
 
-    from jax.experimental.shard_map import shard_map
-
     # Everything but the pod axis stays GSPMD-auto.  The gradient compute is
     # vmapped over a leading pod dim (NOT run inside the manual region: the
     # model is scan-over-layers, and lax.scan inside a partially-auto
-    # shard_map body breaks the SPMD partitioner on the pinned toolchain —
-    # the seed's all-in-one manual pod_step could never compile on a
-    # multi-axis mesh).  Only the cross-pod *reduction* is manual over
-    # ``pod_axis``; that body is scan-free, and it is the one place wire
-    # format matters.
-    auto = frozenset(ax for ax in mesh.axis_names if ax != pod_axis)
+    # shard_map body breaks the SPMD partitioner — an all-in-one manual
+    # pod_step does not compile on a multi-axis mesh).  Only the cross-pod
+    # *reduction* is manual over ``pod_axis``; that body is scan-free, and
+    # it is the one place wire format matters.
     num_pods = mesh.shape[pod_axis]
     data_axis = "data" if "data" in mesh.axis_names else None
 
@@ -187,12 +184,12 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, *,
 
         gspec = jax.tree.map(_stack_spec, grads)
         rspec = jax.tree.map(_stack_spec, residual)
-        reduce_fn = shard_map(
+        reduce_fn = jax.shard_map(
             reduce_body, mesh=mesh, in_specs=(gspec, rspec),
             # the mean leaves replicated; the residual leaves P(pod)-sharded
             # (per-pod local error — never reduced)
             out_specs=(jax.tree.map(lambda _: P(), grads), rspec),
-            check_rep=False, auto=auto)
+            axis_names={pod_axis}, check_vma=False)
         grads, residual = reduce_fn(grads, residual)
         loss = jnp.mean(loss)
         metrics = _pod_metrics(metrics)
@@ -256,7 +253,7 @@ class Trainer:
                 "skipped (use mesh_shape=(1,) for a single-pod mesh)")
         if tcfg.mesh_shape is not None:
             names = (tcfg.pod_axis, "data", "model")[:len(tcfg.mesh_shape)]
-            self.mesh = jax.make_mesh(tuple(tcfg.mesh_shape), names)
+            self.mesh = make_mesh(tuple(tcfg.mesh_shape), names)
             pod_axis = tcfg.pod_axis
             if "model" in names:
                 # hint policy for the GSPMD-auto region *inside* the manual-

@@ -25,6 +25,9 @@ def run_sub(code: str, devices: int = 8, timeout: int = 900,
     instead of exiting cleanly.
     """
     env = dict(os.environ)
+    # The child is a fake-device CPU run by design; it must never contend
+    # with the parent for an accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = REPO_SRC
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

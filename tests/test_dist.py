@@ -84,6 +84,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.optim import AdamWConfig, adamw_update, init_opt_state
         from repro.dist.sharding import MeshAxes, param_pspecs, activation_hint_policy
         from repro.dist.hints import sharding_policy
+        from repro.launch.mesh import make_mesh
         from repro.models.config import ShapeConfig
 
         cfg = ModelConfig(name='t', num_layers=2, d_model=32, num_heads=4,
@@ -108,7 +109,7 @@ def test_sharded_train_step_matches_single_device():
         p_ref, loss_ref = jax.jit(step)(params, opt, toks, labels)
 
         # 8-device mesh
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         ax = MeshAxes(pod="pod")
         psh = jax.tree.map(lambda s: NamedSharding(mesh, s),
                            param_pspecs(cfg, ax),
@@ -138,7 +139,8 @@ def test_compressed_pod_allreduce_close_to_exact():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.dist.compression import compressed_psum_mean, psum_mean
-        mesh = jax.make_mesh((4, 2), ("pod", "data"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("pod", "data"))
         g = jax.random.normal(jax.random.key(0), (4, 64, 128))
 
         def exact(x):
@@ -173,11 +175,12 @@ def test_elastic_checkpoint_reshard():
         import jax, jax.numpy as jnp, numpy as np, tempfile
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.checkpoint import Checkpointer
+        from repro.launch.mesh import make_mesh
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
         d = tempfile.mkdtemp()
         ck = Checkpointer(d)
         ck.save(1, tree, blocking=True)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sh = {"w": NamedSharding(mesh, P("data", "model"))}
         out = ck.restore(tree, shardings=sh)
         assert out["w"].sharding.spec == P("data", "model")

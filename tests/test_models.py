@@ -190,3 +190,31 @@ def test_local_window_changes_long_range_attention():
     l_glob, _ = logits_fn(params, toks, cfg_global)
     assert not np.allclose(np.asarray(l_loc[:, -1]), np.asarray(l_glob[:, -1]),
                            atol=1e-5)
+
+
+@pytest.mark.parametrize("differentiable", [False, True])
+@pytest.mark.parametrize("window", [None, 5])
+def test_chunked_attention_at_lengths_that_are_not_whole_chunks(
+        window, differentiable):
+    """A sequence longer than a chunk but not a whole number of chunks (a
+    543-token prompt against 512-token chunks) equals plain masked softmax
+    attention."""
+    from repro.models.attention import chunked_causal_attention
+
+    S, H, KV, d = 19, 4, 2, 8
+    q, k, v = (jax.random.normal(jax.random.key(i), (2, S, n, d))
+               for i, n in enumerate((H, KV, KV)))
+    out = chunked_causal_attention(q, k, v, scale=d ** -0.5, attn_cap=None,
+                                   window=window, q_chunk=8, kv_chunk=4,
+                                   differentiable=differentiable)
+    kr, vr = (jnp.repeat(x, H // KV, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kr) * d ** -0.5
+    qpos, kpos = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    mask = kpos <= qpos
+    if window is not None:
+        mask &= qpos - kpos < window
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    want = jnp.einsum("bhqk,bkhd->bqhd", p, vr)
+    assert out.shape == want.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)

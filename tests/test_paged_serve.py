@@ -296,3 +296,103 @@ for i in range(3):
     np.testing.assert_array_equal(out[i], want[i])
 print('SHARDED_PAGED_OK')
 """, devices=8)
+
+
+# ---------------------------------------------------------------------------
+# the serve launcher's paged path (what chip_smoke.py drives on the chip)
+# ---------------------------------------------------------------------------
+
+def test_launcher_paged_fused_serves_every_request():
+    from repro.launch import serve
+
+    res = serve.main(["--paged", "--fused-scheduler", "--replicas", "2",
+                      "--requests", "4", "--new-tokens", "5",
+                      "--prompt-lens", "8,12", "--max-len", "32"])
+    stats = res["stats"]
+    assert stats["fused_decisions"] + stats["host_decisions"] == 4
+    assert stats["fused_decisions"] > 0
+    assert stats["allocated"] == stats["freed"]
+    assert [o.shape for o in res["outputs"]] == [(1, 13), (1, 17)] * 2
+    assert sum(stats["processed"].values()) == 4
+
+
+def test_launcher_full_config_cuts_only_depth():
+    from repro.launch import serve
+
+    args = serve.build_parser().parse_args(["--full", "--num-layers", "16"])
+    cfg = serve.served_config(args)
+    assert (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+            cfg.head_dim, cfg.d_ff, cfg.vocab_size, cfg.param_dtype) == (
+        16, 4096, 32, 32, 128, 11008, 102400, "bfloat16")
+    with pytest.raises(SystemExit):
+        serve.served_config(serve.build_parser().parse_args(
+            ["--num-layers", "2"]))
+    with pytest.raises(SystemExit):
+        serve.served_config(serve.build_parser().parse_args(
+            ["--full", "--num-layers", "31"]))
+    # gemma2 alternates local and global layers: depth is cut in pairs.
+    gemma = ["--arch", "gemma2-9b", "--full", "--num-layers"]
+    assert serve.served_config(
+        serve.build_parser().parse_args(gemma + ["4"])).num_layers == 4
+    with pytest.raises(SystemExit):
+        serve.served_config(serve.build_parser().parse_args(gemma + ["3"]))
+
+
+def _serve_paged(cfg, reqs, max_len=32):
+    """Serve ``reqs`` on a paged engine of ``cfg`` over the shared weights,
+    cast to the dtypes ``cfg`` gives them."""
+    from repro.models.model import param_specs
+
+    params = jax.tree.map(lambda x, s: x.astype(s.dtype), _params(),
+                          param_specs(cfg))
+    eng = ServeEngine(cfg, params, max_len=max_len)
+    eng.start_paged(max_batch=4, page_size=8)
+    out = _drain(eng, reqs, list(range(len(reqs))))
+    return eng, [out[i] for i in range(len(reqs))]
+
+
+def test_reference_check_names_the_first_wrong_token():
+    from repro.launch.serve import check_against_reference
+
+    reqs = _requests(4, np.random.default_rng(3))
+    eng, outs = _serve_paged(CFG, reqs)
+    for (prompt, _), seq in zip(reqs, outs):
+        worst, msg = check_against_reference(CFG, eng, prompt, seq)
+        assert msg is None and worst <= 1.0, (worst, msg)
+    prompt, nt = reqs[0]
+    bad = outs[0].copy()
+    k = len(prompt) + nt - 1
+    bad[k] = (bad[k] + 1) % CFG.vocab_size
+    worst, msg = check_against_reference(CFG, eng, prompt, bad)
+    assert msg is not None and msg.startswith(f"step {nt - 1} (position {k})")
+
+
+def test_reference_check_fails_a_path_below_the_stated_precision():
+    """The config states float32; a paged path that computes in bfloat16
+    picks near-tied tokens the float32 reference ranks lower by many
+    float32 ulps, and the check must say so."""
+    from repro.launch.serve import check_against_reference
+
+    reqs = _requests(8, np.random.default_rng(4), nt_max=16)
+    low = dataclasses.replace(CFG, param_dtype="bfloat16",
+                              compute_dtype="bfloat16")
+    eng, outs = _serve_paged(low, reqs)
+    results = [check_against_reference(CFG, eng, p, seq)
+               for (p, _), seq in zip(reqs, outs)]
+    assert any(msg is not None for _, msg in results), results
+
+
+def test_engine_initialises_weights_from_a_key():
+    """A key in place of weights: ``init_params_on``'s values, made by one
+    compiled program in the engine's placement.  They match the eager
+    ``init_params`` to float32 rounding (the compiled init may fuse the
+    scale into the draw)."""
+    from repro.models.model import init_params_on
+
+    eng = ServeEngine(CFG, jax.random.key(0), max_len=32)
+    placed = init_params_on(jax.random.key(0), CFG)
+    for a, b, c in zip(jax.tree.leaves(eng.params), jax.tree.leaves(placed),
+                       jax.tree.leaves(_params())):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=1e-6, atol=0)

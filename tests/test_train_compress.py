@@ -264,6 +264,7 @@ _SUB_PRELUDE = """
     from repro.models import ModelConfig
     from repro.optim import AdamWConfig
     from repro.data import DataConfig
+    from repro.launch.mesh import make_mesh
     from repro.train import Trainer, TrainerConfig
 
     cfg = ModelConfig(name='t', num_layers=2, d_model=32, num_heads=4,
@@ -369,7 +370,7 @@ def test_pod_step_matches_single_device_within_int8_tolerance():
         ).batch_at(0).items()}
         plain = jax.jit(make_train_step(cfg, ocfg))
         p_ref, _, _, m_ref = plain(params, opt, None, batch)
-        mesh = jax.make_mesh((2, 2), ('pod', 'data'))
+        mesh = make_mesh((2, 2), ('pod', 'data'))
         exact = jax.jit(make_train_step(cfg, ocfg, pod_axis='pod',
                                         compress_pods=False, mesh=mesh))
         comp = jax.jit(make_train_step(cfg, ocfg, pod_axis='pod',
