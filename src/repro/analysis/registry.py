@@ -27,6 +27,7 @@ from typing import Callable, Iterable
 # REPRO_LINT_HOT=name1,name2.
 DEFAULT_HOT_FUNCTIONS = frozenset({
     "decode_tick",      # serve/paging.py + serve/engine.py per-tick decode
+    "_run_tick",        # PagedRuntime.decode_tick's body over the live slots
     "decode_step",      # models/model.py traced decode
     "_decode",          # ServeEngine's jitted decode closure site
     "map_event",        # MappingFabric single-event dispatch

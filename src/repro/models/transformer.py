@@ -106,14 +106,17 @@ def apply_sublayer(
         window = cfg.local_window if slot["window"] == "local" else None
         block = attn_mod.mla_block if cfg.attn_type == "mla" else attn_mod.gqa_block
         mixer_cache = cache.get("mixer") if cache else None
-        h, new_mixer_cache = block(params["mixer"], h, cfg, window=window,
-                                   positions=positions, cache=mixer_cache,
-                                   decode_pos=decode_pos,
-                                   differentiable=differentiable)
+        with jax.named_scope("attention"):
+            h, new_mixer_cache = block(params["mixer"], h, cfg, window=window,
+                                       positions=positions, cache=mixer_cache,
+                                       decode_pos=decode_pos,
+                                       differentiable=differentiable)
     else:
         mixer_cache = cache.get("mixer") if cache else None
-        h, new_mixer_cache = mamba_mod.mamba_block(
-            params["mixer"], h, cfg, cache=mixer_cache, decode_pos=decode_pos)
+        with jax.named_scope("mamba"):
+            h, new_mixer_cache = mamba_mod.mamba_block(
+                params["mixer"], h, cfg, cache=mixer_cache,
+                decode_pos=decode_pos)
     if cfg.post_block_norm:
         h = rms_norm(h, params["post_norm_1"], cfg.norm_eps)
     x = x + h
@@ -122,11 +125,12 @@ def apply_sublayer(
     if ffn_kind != "none":
         h = rms_norm(x, params["norm_2"], cfg.norm_eps)
         h = shard_hint(h, "sublayer_input")
-        if ffn_kind == "moe":
-            h, moe_metrics = moe_block(params["ffn"], h, cfg)
-            metrics.update(moe_metrics)
-        else:
-            h = ffn_block(params["ffn"], h, cfg)
+        with jax.named_scope("ffn"):
+            if ffn_kind == "moe":
+                h, moe_metrics = moe_block(params["ffn"], h, cfg)
+                metrics.update(moe_metrics)
+            else:
+                h = ffn_block(params["ffn"], h, cfg)
         if cfg.post_block_norm:
             h = rms_norm(h, params["post_norm_2"], cfg.norm_eps)
         x = x + h

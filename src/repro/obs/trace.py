@@ -15,9 +15,18 @@ Design constraints, in order:
   paths return before touching the ring.  Instrumentation sites guard with
   ``if tracer is not None`` so the *default* runtime path is byte-identical
   to the uninstrumented code.
-* **Bounded memory.**  Events land in a preallocated ring
-  (``capacity`` slots); wraparound drops the oldest events.  A steady-state
-  serving loop can stay instrumented forever without growing the heap.
+* **Bounded memory, invisible to the collector.**  Events land in a
+  preallocated ring (``capacity`` slots); wraparound drops the oldest
+  events.  The ring is kept as columns (one list per field), so a recorded
+  event adds no object the garbage collector tracks -- its ``args`` dict
+  of plain numbers and strings is untracked -- and a full ring does not
+  lengthen the interpreter's full collections.  :class:`TraceEvent`
+  objects are built only when :meth:`Tracer.events` is read.
+* **One clock with the device.**  :meth:`Tracer.phase` and
+  :meth:`Tracer.begin` spans also enter a ``jax.profiler.TraceAnnotation``
+  of the same name, so while a ``jax.profiler`` trace runs they land on
+  its host plane beside the device's operations.  :meth:`Tracer.span`
+  records into the ring alone.
 * **Two clocks.**  Wall-clock events take their timestamp from
   ``time.perf_counter`` relative to the tracer's epoch; simulators pass
   explicit ``ts_us`` values so simulated timelines export on their own
@@ -28,9 +37,12 @@ Timestamps are microseconds (the Chrome trace-event unit).
 
 from __future__ import annotations
 
+import gc
 import io
 import json
 import time
+
+from jax.profiler import TraceAnnotation
 
 _PH_KNOWN = frozenset({"X", "i", "I", "C", "B", "E", "M"})
 
@@ -73,30 +85,54 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        pass
+
+    def end(self, **args):
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
-
 class _Span:
-    """Live span: records one complete ("X") event on exit."""
+    """Live span: records one complete ("X") event on exit.  A mirrored
+    span also holds a profiler annotation of its name open meanwhile."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict | None):
+    def __init__(self, tracer: "Tracer", name: str, args: dict | None,
+                 mirror: bool = False):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._ann = TraceAnnotation(name) if mirror else None
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         tr = self._tracer
-        tr._append(TraceEvent(self._name, "X", (self._t0 - tr._epoch) * 1e6,
-                              (t1 - self._t0) * 1e6, self._args))
+        tr._append(self._name, "X", (self._t0 - tr._epoch) * 1e6,
+                   (t1 - self._t0) * 1e6, self._args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
+
+    def set(self, **args):
+        """Add args known only once the span's work has run."""
+        if self._args is None:
+            self._args = args
+        else:
+            self._args.update(args)
+
+    def end(self, **args):
+        """Close a span opened by :meth:`Tracer.begin`, adding ``args``."""
+        self.set(**args)
+        self.__exit__(None, None, None)
 
 
 class Tracer:
@@ -116,23 +152,39 @@ class Tracer:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = int(capacity)
         self.enabled = bool(enabled)
-        self._ring: list[TraceEvent | None] = [None] * self.capacity
+        self._alloc()
+        self._epoch = time.perf_counter()
+
+    def _alloc(self) -> None:
+        n = self.capacity
+        self._name: list = [None] * n
+        self._ph: list = [None] * n
+        self._ts: list = [0.0] * n
+        self._dur: list = [0.0] * n
+        self._args: list = [None] * n
+        self._tid: list = [0] * n
         self._head = 0          # next write slot
         self._count = 0         # total events ever recorded
-        self._epoch = time.perf_counter()
 
     # -- recording -----------------------------------------------------------
 
-    def _append(self, ev: TraceEvent) -> None:
-        self._ring[self._head] = ev
-        self._head = (self._head + 1) % self.capacity
+    def _append(self, name: str, ph: str, ts: float, dur: float = 0.0,
+                args: dict | None = None, tid: int = 0) -> None:
+        i = self._head
+        self._name[i] = name
+        self._ph[i] = ph
+        self._ts[i] = ts
+        self._dur[i] = dur
+        self._args[i] = args
+        self._tid[i] = tid
+        self._head = (i + 1) % self.capacity
         self._count += 1
 
     def record(self, ev: TraceEvent) -> None:
         """Append a pre-built event (structured-event producers, e.g. the
         fleet controller's decision log, mirror into a shared tracer)."""
         if self.enabled:
-            self._append(ev)
+            self._append(ev.name, ev.ph, ev.ts, ev.dur, ev.args, ev.tid)
 
     def now_us(self) -> float:
         """Current wall-clock timestamp on this tracer's axis (µs)."""
@@ -144,26 +196,52 @@ class Tracer:
             return NULL_SPAN
         return _Span(self, name, args or None)
 
+    def phase(self, name: str, **args):
+        """:meth:`span` that is also a ``jax.profiler.TraceAnnotation`` of
+        ``name``: while a profiler trace runs, it lands on the trace's host
+        plane, on the device's clock."""
+        if not self.enabled:
+            return NULL_SPAN
+        return _Span(self, name, args or None, mirror=True)
+
+    def begin(self, name: str, **args):
+        """Open a :meth:`phase` now, to be closed by its ``end(**args)``
+        from wherever the span's work turns out to stop."""
+        return self.phase(name, **args).__enter__()
+
+    def watch_gc(self):
+        """Context manager: while it is open, every collection of Python's
+        garbage collector records a ``host.gc`` phase with its
+        ``generation`` and ``collected`` count (a ``gc.callbacks`` hook,
+        removed on exit).
+
+        The hook only holds the profiler annotation and notes the times;
+        the spans enter the ring when the context closes, so a collection
+        that interrupts a record call cannot disturb the ring."""
+        if not self.enabled:
+            return NULL_SPAN
+        return _GcWatch(self)
+
     def complete(self, name: str, start_s: float, dur_s: float, **args) -> None:
         """Record a complete event from caller-held wall-clock readings —
         the hot-path alternative to :meth:`span` (one call, no context
         manager).  ``start_s`` is a ``time.perf_counter`` reading."""
         if self.enabled:
-            self._append(TraceEvent(name, "X", (start_s - self._epoch) * 1e6,
-                                    dur_s * 1e6, args or None))
+            self._append(name, "X", (start_s - self._epoch) * 1e6,
+                         dur_s * 1e6, args or None)
 
     def instant(self, name: str, ts_us: float | None = None, **args) -> None:
         """Instant event, at ``ts_us`` (simulated time) or now."""
         if self.enabled:
             ts = self.now_us() if ts_us is None else ts_us
-            self._append(TraceEvent(name, "i", ts, 0.0, args or None))
+            self._append(name, "i", ts, 0.0, args or None)
 
     def counter(self, name: str, ts_us: float | None = None, **values) -> None:
         """Counter ("C") event — Perfetto renders these as track timelines
         (queue depth, backlog, occupancy).  Values must be numeric."""
         if self.enabled:
             ts = self.now_us() if ts_us is None else ts_us
-            self._append(TraceEvent(name, "C", ts, 0.0, values))
+            self._append(name, "C", ts, 0.0, values)
 
     # -- inspection / export -------------------------------------------------
 
@@ -177,17 +255,17 @@ class Tracer:
 
     def events(self) -> list[TraceEvent]:
         """Buffered events, oldest first."""
-        n = len(self)
+        cols = (self._name, self._ph, self._ts, self._dur, self._args,
+                self._tid)
         if self._count <= self.capacity:
-            return [e for e in self._ring[:n]]
-        # wrapped: head points at the oldest slot
-        return [self._ring[(self._head + i) % self.capacity]
-                for i in range(self.capacity)]
+            cols = [c[:self._count] for c in cols]
+        else:   # wrapped: head points at the oldest slot
+            h = self._head
+            cols = [c[h:] + c[:h] for c in cols]
+        return [TraceEvent(*row) for row in zip(*cols)]
 
     def clear(self) -> None:
-        self._ring = [None] * self.capacity
-        self._head = 0
-        self._count = 0
+        self._alloc()
 
     def to_chrome(self, *, metrics=None) -> dict:
         """Chrome trace-event JSON object format.
@@ -216,6 +294,41 @@ class Tracer:
 
 
 NULL_TRACER = Tracer(capacity=1, enabled=False)
+
+
+class _GcWatch:
+    """:meth:`Tracer.watch_gc`'s ``gc.callbacks`` hook."""
+
+    __slots__ = ("_tracer", "_open", "_done")
+
+    def __init__(self, tracer: Tracer):
+        self._tracer = tracer
+        self._open = None       # (annotation, start) of a running collection
+        self._done: list = []   # (start, end, generation, collected)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            ann = TraceAnnotation("host.gc")
+            ann.__enter__()
+            self._open = (ann, time.perf_counter())
+        elif self._open is not None:
+            ann, t0 = self._open
+            self._open = None
+            self._done.append((t0, time.perf_counter(), info["generation"],
+                               info["collected"]))
+            ann.__exit__(None, None, None)
+
+    def __enter__(self):
+        gc.callbacks.append(self._on_gc)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self._on_gc)
+        done, self._done = self._done, []
+        for t0, t1, gen, collected in done:
+            self._tracer.complete("host.gc", t0, t1 - t0, generation=gen,
+                                  collected=collected)
+        return False
 
 
 # ---------------------------------------------------------------------------

@@ -56,6 +56,7 @@ from repro.dist.sharding import MeshAxes, named, replica_pspecs, reshard_tree
 from repro.models.config import ModelConfig
 from repro.models.model import decode_step, init_params_on, prefill_step
 from repro.obs.metrics import Stopwatch
+from repro.obs.trace import NULL_SPAN
 
 
 def _host_scale_s(prompt_tokens, new_tokens):
@@ -295,12 +296,18 @@ class ServeEngine:
         Reserves the request's full page budget up front; returns the slot
         id, or ``None`` when the pool lacks a slot/pages — callers queue
         rejected requests (the contract is queue-never-drop; see
-        ``HeftFrontEnd.run_continuous``).
+        ``HeftFrontEnd.run_continuous``).  A traced call's ``engine.admit``
+        span says whether it ``admitted`` (1) or was refused (0).
         """
         rt = self._require_paged()
-        with _span(self.tracer, "engine.admit",
-                   S0=int(np.asarray(prompt).size), new_tokens=new_tokens):
+        tr = self.tracer
+        if tr is None:
             return rt.admit(prompt, new_tokens)
+        with tr.span("engine.admit", S0=int(np.asarray(prompt).size),
+                     new_tokens=new_tokens) as span:
+            slot = rt.admit(prompt, new_tokens)
+            span.set(admitted=int(slot is not None))
+        return slot
 
     def decode_tick(self, sched=None):
         """One decode step for every in-flight slot → {slot: new token}.
@@ -311,11 +318,14 @@ class ServeEngine:
         decision runs *inside* the tick's compiled program against the
         fabric's device-resident registers, and the call returns
         ``(tokens, decision)`` instead (see ``PagedRuntime.decode_tick``
-        and docs/scheduling.md)."""
-        rt = self._require_paged()
-        with _span(self.tracer, "engine.decode_tick",
-                   active=len(rt.active_slots()), fused=sched is not None):
-            return rt.decode_tick(sched)
+        and docs/scheduling.md).
+
+        With a tracer attached, a tick that runs at least one lane records
+        an ``engine.decode_tick`` span (``active``, ``fused``,
+        ``pages_reserved``, ``pages_written``) around ``tick.stage``,
+        ``tick.wait`` and ``tick.commit`` phases; an empty tick records
+        nothing."""
+        return self._require_paged().decode_tick(sched)
 
     def finished_slots(self) -> list[int]:
         """Slots whose generation completed and await :meth:`retire`."""
@@ -387,6 +397,73 @@ class ReplicaHandle:
                 self.compute_tflops *= scale
             if self.hbm_gbps:
                 self.hbm_gbps *= scale
+
+
+class _Lifecycles:
+    """``run_continuous``'s traced bookkeeping: one ``request`` span per
+    request, the ``sched.*`` phases of each mapping event (numbered
+    ``ev``), and one ``loop.idle`` phase per run of loop iterations with
+    nothing in flight, queued or pending.  Built only when a tracer is
+    attached; while entered, it also records ``host.gc``
+    (:meth:`~repro.obs.trace.Tracer.watch_gc`)."""
+
+    def __init__(self, tracer):
+        self.tr = tracer
+        self.ev = 0             # the mapping event being decided
+        # req idx → [seen, decided, admitted, first token, ev, path,
+        # replica], times on the perf_counter clock
+        self.reqs: dict[int, list] = {}
+        self.idle = None        # open loop.idle phase
+        self.idle_n = 0
+        self.gc = None          # the Tracer.watch_gc hook while entered
+
+    def iteration(self, batch, pending, queues, replicas) -> None:
+        now = time.perf_counter()
+        for i in batch:
+            self.reqs[i] = [now, now, now, now, -1, "", -1]
+        busy = (batch or pending or any(queues)
+                or any(r.engine.paged.slots for r in replicas))
+        if not busy:
+            if self.idle is None:
+                self.idle = self.tr.begin("loop.idle")
+                self.idle_n = 0
+            self.idle_n += 1
+        elif self.idle is not None:
+            self.idle.end(iterations=self.idle_n)
+            self.idle = None
+
+    def phase(self, name: str, reqs: list[int], path: str):
+        return self.tr.phase(name, ev=self.ev, n=len(reqs), path=path)
+
+    def decided(self, reqs: list[int], path: str) -> None:
+        now = time.perf_counter()
+        for i in reqs:
+            rec = self.reqs[i]
+            rec[1], rec[4], rec[5] = now, self.ev, path
+        self.ev += 1
+
+    def admitted(self, i: int, replica: int, t0: float) -> None:
+        rec = self.reqs[i]
+        rec[2], rec[3], rec[6] = t0, time.perf_counter(), replica
+
+    def retired(self, i: int, tokens: int) -> None:
+        seen, decided, admitted, first, ev, path, replica = self.reqs.pop(i)
+        self.tr.complete("request", seen, time.perf_counter() - seen,
+                         rid=i, replica=replica, path=path, ev=ev,
+                         tokens=tokens, decided_s=decided - seen,
+                         admitted_s=admitted - seen,
+                         first_token_s=first - seen)
+
+    def __enter__(self):
+        self.gc = self.tr.watch_gc().__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self.idle is not None:
+            self.idle.end(iterations=self.idle_n)
+            self.idle = None
+        self.gc.__exit__(*exc)
+        return False
 
 
 @dataclass
@@ -607,6 +684,17 @@ class HeftFrontEnd:
         with ``ticks``, per-replica ``processed``, the pools' cumulative
         ``allocated`` / ``freed`` page counters (equal at drain), and the
         ``fused_decisions`` / ``host_decisions`` split.
+
+        With a tracer attached (``self.tracer``; engines carry their own),
+        the loop records a ``request`` span per request (``rid``,
+        ``replica``, ``path``, ``ev``, ``tokens`` and the offsets
+        ``decided_s`` / ``admitted_s`` / ``first_token_s`` from when the
+        loop first saw it due to its retirement), ``sched.stage`` /
+        ``sched.decide`` / ``sched.adopt`` phases per mapping event (``ev``,
+        ``n``, ``path``), one ``loop.idle`` phase per run of iterations with
+        nothing in flight, queued or pending (``iterations``), and a
+        ``host.gc`` phase per garbage collection while it runs (docs/knobs.md
+        "Observability").  Untraced, each site costs one ``is None`` check.
         """
         arrivals = arrival_ticks or [0] * len(requests)
         if len(arrivals) != len(requests):
@@ -639,71 +727,103 @@ class HeftFrontEnd:
         pending: list[int] = []     # fused path: arrived, not yet mapped
         tick = 0
         next_arrival = 0
-        while len(outputs) < len(requests):
-            # 1. HEFT_RT-map the newly arrived requests (sticky decisions).
-            batch = []
-            while (next_arrival < len(order)
-                   and arrivals[order[next_arrival]] <= tick):
-                batch.append(order[next_arrival])
-                next_arrival += 1
-            carrier = None
-            if not fused:
-                if batch:
-                    plan = self.schedule([requests[i] for i in batch])
-                    for req_i, rep_i in plan:
-                        queues[rep_i].append(batch[req_i])
-            else:
-                pending.extend(batch)
-                if pending:
-                    # The decision rides the first replica that will run a
-                    # decode tick this round; with nothing in flight there
-                    # is no tick to ride — take the host path now (against
-                    # the same resident registers) so this tick admits.
-                    carrier = next(
-                        (i for i, r in enumerate(self.replicas)
-                         if r.engine.paged is not None
-                         and r.engine.paged.active_slots()), None)
-                    if carrier is None:
-                        avg, ex = self._stage_event(
-                            [requests[i] for i in pending])
-                        decision = self.fabric.map_event(avg, ex)
-                        plan = self._adopt_decision(len(pending), decision)
-                        host_decisions += len(pending)
+        tr = self.tracer
+        life = _Lifecycles(tr) if tr is not None else None
+        with NULL_SPAN if life is None else life:
+            while len(outputs) < len(requests):
+                # 1. HEFT_RT-map the newly arrived requests (sticky decisions).
+                batch = []
+                while (next_arrival < len(order)
+                       and arrivals[order[next_arrival]] <= tick):
+                    batch.append(order[next_arrival])
+                    next_arrival += 1
+                if life is not None:
+                    life.iteration(batch, pending, queues, self.replicas)
+                carrier = None
+                if not fused:
+                    if batch:
+                        with (NULL_SPAN if life is None else
+                              life.phase("sched.decide", batch, "host")):
+                            plan = self.schedule([requests[i] for i in batch])
                         for req_i, rep_i in plan:
-                            queues[rep_i].append(pending[req_i])
-                        pending = []
-            # 2. Admission tick: drain each mapped queue into free slots.
-            for rep_i, r in enumerate(self.replicas):
-                while queues[rep_i]:
-                    idx = queues[rep_i][0]
-                    prompt, nt = requests[idx]
-                    slot = r.engine.admit(prompt, nt)
-                    if slot is None:       # exhausted: stays queued (FIFO)
-                        break
-                    queues[rep_i].pop(0)
-                    slot_of[(rep_i, slot)] = idx
-            # 3. Decode tick + retire finished slots.  On the fused path the
-            # carrier's tick also computes the pending arrivals' mapping
-            # inside its compiled program; the mapped requests reach their
-            # queues for the NEXT admission tick (a one-tick pipeline
-            # delay — the steady-state cost of zero host round-trips).
-            for rep_i, r in enumerate(self.replicas):
-                if fused and pending and rep_i == carrier:
-                    avg, ex = self._stage_event(
-                        [requests[i] for i in pending])
-                    _, decision = r.engine.decode_tick((avg, ex, self.fabric))
-                    plan = self._adopt_decision(len(pending), decision)
-                    fused_decisions += len(pending)
-                    for req_i, rep_to in plan:
-                        queues[rep_to].append(pending[req_i])
-                    pending = []
+                            queues[rep_i].append(batch[req_i])
+                        if life is not None:
+                            life.decided(batch, "host")
                 else:
-                    r.engine.decode_tick()
-                for slot in r.engine.finished_slots():
-                    idx = slot_of.pop((rep_i, slot))
-                    outputs[idx] = r.engine.retire(slot)
-                    r.processed += 1
-            tick += 1
+                    pending.extend(batch)
+                    if pending:
+                        # The decision rides the first replica that will run
+                        # a decode tick this round; with nothing in flight
+                        # there is no tick to ride — take the host path now
+                        # (against the same resident registers) so this tick
+                        # admits.
+                        carrier = next(
+                            (i for i, r in enumerate(self.replicas)
+                             if r.engine.paged is not None
+                             and r.engine.paged.active_slots()), None)
+                        if carrier is None:
+                            with (NULL_SPAN if life is None else
+                                  life.phase("sched.stage", pending, "host")):
+                                avg, ex = self._stage_event(
+                                    [requests[i] for i in pending])
+                            with (NULL_SPAN if life is None else
+                                  life.phase("sched.decide", pending, "host")):
+                                decision = self.fabric.map_event(avg, ex)
+                            with (NULL_SPAN if life is None else
+                                  life.phase("sched.adopt", pending, "host")):
+                                plan = self._adopt_decision(len(pending),
+                                                            decision)
+                            host_decisions += len(pending)
+                            for req_i, rep_i in plan:
+                                queues[rep_i].append(pending[req_i])
+                            if life is not None:
+                                life.decided(pending, "host")
+                            pending = []
+                # 2. Admission tick: drain each mapped queue into free slots.
+                for rep_i, r in enumerate(self.replicas):
+                    while queues[rep_i]:
+                        idx = queues[rep_i][0]
+                        prompt, nt = requests[idx]
+                        t0 = time.perf_counter() if life is not None else 0
+                        slot = r.engine.admit(prompt, nt)
+                        if slot is None:       # exhausted: stays queued (FIFO)
+                            break
+                        queues[rep_i].pop(0)
+                        slot_of[(rep_i, slot)] = idx
+                        if life is not None:
+                            life.admitted(idx, rep_i, t0)
+                # 3. Decode tick + retire finished slots.  On the fused path
+                # the carrier's tick also computes the pending arrivals'
+                # mapping inside its compiled program; the mapped requests
+                # reach their queues for the NEXT admission tick (a one-tick
+                # pipeline delay — the steady-state cost of zero host
+                # round-trips).
+                for rep_i, r in enumerate(self.replicas):
+                    if fused and pending and rep_i == carrier:
+                        with (NULL_SPAN if life is None else
+                              life.phase("sched.stage", pending, "fused")):
+                            avg, ex = self._stage_event(
+                                [requests[i] for i in pending])
+                        _, decision = r.engine.decode_tick(
+                            (avg, ex, self.fabric))
+                        with (NULL_SPAN if life is None else
+                              life.phase("sched.adopt", pending, "fused")):
+                            plan = self._adopt_decision(len(pending), decision)
+                        fused_decisions += len(pending)
+                        for req_i, rep_to in plan:
+                            queues[rep_to].append(pending[req_i])
+                        if life is not None:
+                            life.decided(pending, "fused")
+                        pending = []
+                    else:
+                        r.engine.decode_tick()
+                    for slot in r.engine.finished_slots():
+                        idx = slot_of.pop((rep_i, slot))
+                        outputs[idx] = r.engine.retire(slot)
+                        r.processed += 1
+                        if life is not None:
+                            life.retired(idx, requests[idx][1])
+                tick += 1
         stats = {
             "ticks": tick,
             "processed": {r.name: r.processed for r in self.replicas},

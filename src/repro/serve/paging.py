@@ -66,6 +66,7 @@ from jax.tree_util import tree_map_with_path
 
 from repro.kernels.fused_decision import decision_ref, pack_tick_outputs
 from repro.obs.device import accumulate_counters
+from repro.obs.trace import NULL_SPAN
 from repro.sched_integration.fabric import pow2_bucket
 
 # Leaf classification by name — the same convention _cache_rule uses.
@@ -265,10 +266,15 @@ def paged_programs(cfg, page_size: int, pages_per_slot: int) -> dict:
 
         return tree_map_with_path(s, pools, new_caches)
 
+    # Named scopes (HLO metadata only) mark the page gather, the page
+    # scatter and the in-tick decision; attention and the FFN are scoped
+    # in models/transformer.py.
     def tick(params, pools, table, slot_ids, pos, tok):
-        dense = gather(pools, table, slot_ids)
+        with jax.named_scope("page_gather"):
+            dense = gather(pools, table, slot_ids)
         logits, new_caches = decode_step(params, dense, tok, pos, cfg)
-        pools = scatter_token(pools, new_caches, table, slot_ids, pos)
+        with jax.named_scope("page_scatter"):
+            pools = scatter_token(pools, new_caches, table, slot_ids, pos)
         # Greedy selection INSIDE the jitted program: the host only ever
         # transfers the (B,) winning tokens, never the (B, V) logits —
         # same argmax the dense oracle computes, one op earlier
@@ -285,7 +291,8 @@ def paged_programs(cfg, page_size: int, pages_per_slot: int) -> dict:
     def tick_sched(params, pools, table, slot_ids, pos, tok,
                    a_p, ex_p, valid, avail, mask):
         toks, pools = tick(params, pools, table, slot_ids, pos, tok)
-        res = decision_ref(a_p, ex_p, avail, valid, mask)
+        with jax.named_scope("heft_rt_decision"):
+            res = decision_ref(a_p, ex_p, avail, valid, mask)
         # Tokens + decision leave the device as ONE packed int32 buffer
         # (see pack_tick_outputs): per-output host syncs would cost more
         # than the decision itself.  new_avail additionally rides out as
@@ -296,7 +303,8 @@ def paged_programs(cfg, page_size: int, pages_per_slot: int) -> dict:
                            a_p, ex_p, valid, avail, mask, counters,
                            p_valid):
         toks, pools = tick(params, pools, table, slot_ids, pos, tok)
-        res = decision_ref(a_p, ex_p, avail, valid, mask)
+        with jax.named_scope("heft_rt_decision"):
+            res = decision_ref(a_p, ex_p, avail, valid, mask)
         counters = accumulate_counters(counters, res.assignment,
                                        res.new_avail, valid, p_valid)
         return pack_tick_outputs(toks, res), pools, res.new_avail, counters
@@ -497,6 +505,24 @@ class PagedRuntime:
         active = self.active_slots()
         if not active:
             return {} if sched is None else ({}, None)
+        tr = self.engine.tracer
+        if tr is None:
+            return self._run_tick(active, sched, None)
+        ps = self.pool.page_size
+        reserved = sum(len(self.slots[s].pages) for s in active)
+        written = sum(self.slots[s].write_pos // ps + 1 for s in active)
+        with tr.span("engine.decode_tick", active=len(active),
+                     fused=sched is not None, pages_reserved=reserved,
+                     pages_written=written):
+            return self._run_tick(active, sched, tr)
+
+    def _run_tick(self, active: list[int], sched, tr):
+        """:meth:`decode_tick` over the live slots ``active``; ``tr`` (a
+        tracer, or None) times its ``tick.stage`` / ``tick.wait`` /
+        ``tick.commit`` phases.  The dispatch of the jitted call lies
+        between stage and wait."""
+        stage = NULL_SPAN if tr is None else tr.phase("tick.stage")
+        stage.__enter__()
         B = pow2_bucket(len(active), 1)
         scratch = self.pool.scratch_slot
         lanes = active + [scratch] * (B - len(active))
@@ -514,13 +540,18 @@ class PagedRuntime:
                     jnp.asarray(self.pool.table[slot_ids]),
                     jnp.asarray(slot_ids), jnp.asarray(pos), jnp.asarray(tok))
             if sched is None:
+                stage.__exit__(None, None, None)
                 toks, self.pool.pools = self._tick(*args)
-                nxt = np.asarray(toks)
+                with NULL_SPAN if tr is None else tr.phase("tick.wait"):
+                    nxt = np.asarray(toks)
+                commit = NULL_SPAN if tr is None else tr.phase("tick.commit")
+                commit.__enter__()
             else:
                 avg, exec_times, fab = sched
                 n = len(avg)
                 (a_p, ex_p, valid, avail, mask,
                  counters, p_valid) = fab.tick_decision_inputs(avg, exec_times)
+                stage.__exit__(None, None, None)
                 if counters is None:
                     packed, self.pool.pools, new_avail = self._tick_sched(
                         *args, a_p, ex_p, valid, avail, mask)
@@ -535,7 +566,10 @@ class PagedRuntime:
                 # The tick's single host sync: tokens and decision share one
                 # packed buffer (pack_tick_outputs); new_avail/ctr stay
                 # device-resident and are adopted back by the fabric.
-                buf = np.asarray(packed)
+                with NULL_SPAN if tr is None else tr.phase("tick.wait"):
+                    buf = np.asarray(packed)
+                commit = NULL_SPAN if tr is None else tr.phase("tick.commit")
+                commit.__enter__()
                 nxt = buf[:B]
                 decision = fab.commit_tick_decision(n, buf[B:], new_avail,
                                                     ctr)
@@ -544,6 +578,7 @@ class PagedRuntime:
             t = int(nxt[i])
             self.slots[s].tokens.append(t)
             out[s] = t
+        commit.__exit__(None, None, None)
         return out if sched is None else (out, decision)
 
     def retire(self, slot: int) -> np.ndarray:

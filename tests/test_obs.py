@@ -169,6 +169,49 @@ def test_disabled_tracer_is_noop():
     assert len(NULL_TRACER) == 0
 
 
+def test_recorded_events_add_no_tracked_objects():
+    """The ring is columns of plain values: a full ring gives the garbage
+    collector nothing more to traverse."""
+    import gc
+
+    tr = Tracer(capacity=4096)
+    gc.collect()
+    before = len(gc.get_objects())
+    for i in range(2000):
+        with tr.span("s", i=i, tag="x"):
+            pass
+        tr.complete("c", 0.0, 1e-6, n=i)
+    gc.collect()
+    assert len(gc.get_objects()) - before < 100
+    evs = tr.events()
+    assert len(evs) == 4000 and [e.name for e in evs[:2]] == ["s", "c"]
+    assert evs[0].args == {"i": 0, "tag": "x"} and evs[-1].args == {"n": 1999}
+
+
+def test_phase_begin_and_gc_watch():
+    import gc
+
+    tr = Tracer()
+    with tr.phase("p", ev=1) as sp:
+        sp.set(n=2)
+    opened = tr.begin("idle")
+    opened.end(iterations=3)
+    before = list(gc.callbacks)
+    with tr.watch_gc():
+        gc.collect()
+    assert gc.callbacks == before
+    spans = {e.name: e for e in tr.events()}
+    assert spans["p"].args == {"ev": 1, "n": 2} and spans["p"].ph == "X"
+    assert spans["idle"].args == {"iterations": 3}
+    assert spans["host.gc"].args["generation"] == 2
+    off = Tracer(enabled=False)
+    assert off.phase("p") is NULL_SPAN and off.begin("q") is NULL_SPAN
+    assert off.watch_gc() is NULL_SPAN
+    with off.watch_gc():
+        gc.collect()
+    assert len(off) == 0
+
+
 def test_chrome_export_schema(tmp_path):
     tr = Tracer()
     with tr.span("outer", tag="t"):
