@@ -19,12 +19,14 @@ Design notes
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from repro.dist.hints import shard_hint
+from repro.kernels import paged_attention as paged_kernel
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope, dense_init, dtype_of, rms_norm, softcap
 
@@ -238,6 +240,46 @@ def decode_attention(
     return out.reshape(B, 1, H, d).astype(q.dtype)
 
 
+class PagedKV(NamedTuple):
+    """One attention layer's keys and values left in the paged decode
+    tick's page pool (``serve/paging.py``), in place of a dense cache."""
+
+    k: jax.Array          # (L, num_pages + 1, page_size, KV, d): all layers
+    v: jax.Array
+    layer: jax.Array      # () int32: this layer's index into ``k``/``v``
+    table: jax.Array      # (B, pages_per_slot) int32: each lane's page ids
+
+
+def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, layer, table,
+                           pos, *, scale: float) -> jax.Array:
+    """One query per lane against keys ``0..pos``: the lane's cached pages
+    of ``layer`` plus its own new key/value (``(B, 1, KV, d)``, not yet in
+    the pool).  On a TPU the Pallas kernel reads the live pages in place
+    (``kernels/paged_attention.py``); elsewhere this gathers the layer's
+    pages into a dense view, writes the new token at ``pos`` and runs
+    :func:`decode_attention`, the same operations as a dense cache."""
+    if paged_kernel.use_kernel():
+        return paged_kernel.paged_decode_attention_kernel(
+            q, k_new, v_new, pool_k, pool_v, layer, table, pos, scale=scale)
+    return paged_decode_attention_ref(q, k_new, v_new, pool_k, pool_v, layer,
+                                      table, pos, scale=scale)
+
+
+def paged_decode_attention_ref(q, k_new, v_new, pool_k, pool_v, layer, table,
+                               pos, *, scale: float) -> jax.Array:
+    """:func:`paged_decode_attention` through a dense view of one layer."""
+    B, pp = table.shape
+    rows = jnp.arange(B)
+
+    def view(pool, new):
+        pages = pool[layer][table]                 # (B, pp, ps, KV, d)
+        dense = pages.reshape(B, pp * pages.shape[2], *pages.shape[3:])
+        return dense.at[rows, pos].set(new[:, 0])
+
+    return decode_attention(q, view(pool_k, k_new), view(pool_v, v_new),
+                            pos, scale=scale, attn_cap=None, window=None)
+
+
 def gqa_block(
     params: dict,
     x: jax.Array,             # (B, S, D)
@@ -245,7 +287,7 @@ def gqa_block(
     *,
     window: int | None,
     positions: jax.Array,     # (S,) or scalar decode position
-    cache: dict | None = None,  # {'k': (B,Smax,KV,d), 'v': ...}
+    cache: dict | PagedKV | None = None,  # {'k': (B,Smax,KV,d), 'v': ...}
     decode_pos: jax.Array | None = None,
     differentiable: bool = False,
 ) -> tuple[jax.Array, dict | None]:
@@ -270,7 +312,17 @@ def gqa_block(
         v = shard_hint(v, "attn_kv")
 
     new_cache = None
-    if decode_pos is not None:
+    if isinstance(cache, PagedKV):
+        # Paged decode tick: attend in the pool, hand back only the new
+        # token's K/V (the tick writes it to its page after the layers).
+        assert window is None and cfg.attn_softcap is None and S == 1
+        k_new = k.astype(cache.k.dtype)
+        v_new = v.astype(cache.v.dtype)
+        out = paged_decode_attention(q, k_new, v_new, cache.k, cache.v,
+                                     cache.layer, cache.table, decode_pos,
+                                     scale=scale)
+        new_cache = {"k": k_new[:, 0], "v": v_new[:, 0]}
+    elif decode_pos is not None:
         assert cache is not None and S == 1
         if jnp.ndim(decode_pos) == 0:
             k_cache = lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), decode_pos, axis=1)

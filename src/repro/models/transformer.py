@@ -179,10 +179,14 @@ def apply_stack(
         # doubling the stacked-residual footprint).  Its VJP barriers the
         # cotangent too, protecting the backward residual stream.
         x = lax.optimization_barrier(x)
-        sp, c = stage_in
+        sp, c, layer = stage_in
         new_cache = {}
         for j, slot in enumerate(plan):
-            sub_cache = c.get(f"sub{j}") if c is not None else None
+            if f"sub{j}" in in_place:
+                paged = in_place[f"sub{j}"]["mixer"]._replace(layer=layer)
+                sub_cache = {"mixer": paged}
+            else:
+                sub_cache = c.get(f"sub{j}") if c is not None else None
             x, nc, met = apply_sublayer(sp[f"sub{j}"], x, cfg, slot,
                                         positions=positions, cache=sub_cache,
                                         decode_pos=decode_pos,
@@ -202,14 +206,26 @@ def apply_stack(
         body = jax.checkpoint(stage_body,
                               policy=jax.checkpoint_policies.nothing_saveable)
 
-    xs = (stage_params, caches["stages"]) if caches else (stage_params, None)
+    # Paged decode: a sublayer whose cache is a ``PagedKV`` attends in the
+    # page pool, which holds every layer.  Its pool stays out of the scan's
+    # xs (loop-invariant: nothing slices or copies it per layer), each
+    # layer gets its index from the scan, and the scan stacks only the new
+    # token's K/V, (num_stages, B, KV, d).
+    in_place = {}
+    if caches is not None:
+        in_place = {n: c for n, c in caches["stages"].items()
+                    if isinstance(c.get("mixer"), attn_mod.PagedKV)}
     if caches is None:
         # scan needs a concrete xs pytree; feed params only
-        (x, agg), _ = lax.scan(lambda c, sp: body(c, (sp, None)),
+        (x, agg), _ = lax.scan(lambda c, sp: body(c, (sp, None, None)),
                                (x, agg_init), stage_params)
         new_stage_caches = None
     else:
-        (x, agg), new_stage_caches = lax.scan(body, (x, agg_init), xs)
+        scanned = {n: c for n, c in caches["stages"].items()
+                   if n not in in_place}
+        layers = jnp.arange(cfg.num_stages, dtype=jnp.int32)
+        (x, agg), new_stage_caches = lax.scan(
+            body, (x, agg_init), (stage_params, scanned, layers))
 
     new_caches = None
     if caches is not None:

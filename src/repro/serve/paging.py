@@ -34,17 +34,22 @@ never drop — rejected requests.
 
 Decode tick
 -----------
-Each tick gathers the active slots' pages into a dense-shaped
-``(B, Smax, ...)`` view, runs the standard ``decode_step`` with a per-row
-position vector, and scatters only the newly written token back to its
-page.  Rows are independent in every einsum/softmax of the model, stale
-garbage beyond a row's position is masked to ``-inf`` before softmax (pool
-values are always finite), and RoPE sees the same per-row positions — so
+Each tick runs the standard ``decode_step`` with a per-row position vector
+and writes back only the newly written token to its page.  A plain
+attention layer (:func:`in_place_layers`) attends in the pool: the layer
+scan takes the pool as a loop-invariant operand and
+``models.attention.paged_decode_attention`` reads each lane's live pages
+(in place on a TPU), so no dense cache exists.  Every other layer gathers
+the active slots' pages into a dense-shaped ``(B, Smax, ...)`` view.  Rows
+are independent in every einsum/softmax of the model, stale garbage beyond
+a row's position is masked to ``-inf`` before softmax (pool values are
+always finite), and RoPE sees the same per-row positions — so off the TPU
 each request's tokens are **bit-identical** to the dense single-request
-oracle (``ServeEngine.generate``), under any admission interleaving.  The
-active-lane count is padded to a power-of-two bucket (same idiom as
-``MappingFabric``; ``sched_integration.fabric.pow2_bucket``), so joins and
-leaves retrace at most ``log2(max_batch) + 1`` decode variants.
+oracle (``ServeEngine.generate``), under any admission interleaving; the
+TPU kernel matches it to f32 rounding.  The active-lane count is padded to
+a power-of-two bucket (same idiom as ``MappingFabric``;
+``sched_integration.fabric.pow2_bucket``), so joins and leaves retrace at
+most ``log2(max_batch) + 1`` decode variants.
 
 Pages are also the migration and recovery unit: :meth:`PagedRuntime
 .snapshot_slot` captures one request's page set (plus its host-side decode
@@ -215,23 +220,57 @@ class PagePool:
         return len(self.free_slot_ids)
 
 
-def paged_programs(cfg, page_size: int, pages_per_slot: int) -> dict:
+def in_place_layers(cfg, pools, in_place: bool = True) -> dict:
+    """Which attention layers the paged tick reads in the pool, by the
+    structure of their cache leaves: ``{("first", i) | ("stages", "subj"):
+    bool}``, one entry per attention sublayer.
+
+    A layer attends in place when its leaves are a plain paged ``k``/``v``
+    pair and it has no local window and no attention soft-cap.  MLA
+    (``ckv``/``kr``) and windowed or capped layers gather a dense view, as
+    does every layer when ``in_place`` is false (a sharded engine: the
+    kernel is not partitioned over KV heads).  State leaves (``conv``/
+    ``ssm``) are not attention and have no entry.
+    """
+    from repro.models.transformer import _sublayer_plan
+
+    def direct(window, sub):
+        return (in_place and window != "local" and cfg.attn_softcap is None
+                and set(sub["mixer"]) == {"k", "v"})
+
+    out = {("first", i): direct(cfg.window_kind(0), sub)
+           for i, sub in enumerate(pools["first"])}
+    for j, slot in enumerate(_sublayer_plan(cfg)):
+        if slot["kind"] == "attn":
+            name = f"sub{j}"
+            out[("stages", name)] = direct(slot["window"],
+                                           pools["stages"][name])
+    return out
+
+
+def paged_programs(cfg, page_size: int, pages_per_slot: int,
+                   in_place: bool = True) -> dict:
     """The paged runtime's device programs as plain (unjitted) functions.
 
     ``tick``, ``tick_sched``, ``tick_sched_counted``, ``admit_scatter`` and
     ``restore_scatter`` over pool trees of ``page_size``-token pages,
     ``pages_per_slot`` per sequence.  :class:`PagedRuntime` jits them for
     its engine's placement; a compile-only check can lower them from
-    abstract shapes for a device that is not attached.
+    abstract shapes for a device that is not attached.  ``in_place``:
+    whether the tick may read attention layers in the pool
+    (:func:`in_place_layers`); false gathers every layer's dense view.
     """
+    from repro.models.attention import PagedKV
     from repro.models.model import decode_step
 
     pp, ps = pages_per_slot, page_size
 
     def gather(pools, table, slot_ids):
-        """pools + (B, pp) table + (B,) slot ids → dense (B, Smax, ...)
-        cache view."""
+        """pools + (B, pp) table + (B,) slot ids → the tick's cache tree:
+        a :class:`PagedKV` over the pool for each in-place layer, else the
+        dense (B, Smax, ...) view of the lanes' pages."""
         B = table.shape[0]
+        direct = in_place_layers(cfg, pools, in_place)
 
         def g(path, pool):
             paged, stacked = _leaf_kind(path)
@@ -244,11 +283,24 @@ def paged_programs(cfg, page_size: int, pages_per_slot: int) -> dict:
                 return v.reshape(B, pp * ps, *v.shape[3:])
             return pool[:, slot_ids] if stacked else pool[slot_ids]
 
-        return tree_map_with_path(g, pools)
+        def sub(where, tree):
+            if not direct.get(where):
+                return tree_map_with_path(lambda p, x: g(where + p, x), tree)
+            kv = tree["mixer"]
+            if where[0] == "first":             # one layer: give it an axis
+                kv = {n: a[None] for n, a in kv.items()}
+            return {"mixer": PagedKV(kv["k"], kv["v"], jnp.int32(0), table)}
+
+        return {"first": [sub(("first", i), t)
+                          for i, t in enumerate(pools["first"])],
+                "stages": {n: sub(("stages", n), t)
+                           for n, t in pools["stages"].items()}}
 
     def scatter_token(pools, new_caches, table, slot_ids, pos):
         """Write back only what the tick changed: the one token each lane
-        wrote at ``pos`` (paged leaves) and the rolled state rows."""
+        wrote at ``pos`` (paged leaves) and the rolled state rows.  An
+        in-place layer's new token comes as (L, B, ...) or (B, ...), a
+        gathered layer's inside its dense (…, B, Smax, ...) view."""
         B = table.shape[0]
         rows = jnp.arange(B)
         page = table[rows, pos // ps]           # (B,) target page ids
@@ -257,9 +309,12 @@ def paged_programs(cfg, page_size: int, pages_per_slot: int) -> dict:
         def s(path, pool, new):
             paged, stacked = _leaf_kind(path)
             if paged:
+                dense = new.ndim == pool.ndim
                 if stacked:
-                    return pool.at[:, page, off].set(new[:, rows, pos])
-                return pool.at[page, off].set(new[rows, pos])
+                    return pool.at[:, page, off].set(
+                        new[:, rows, pos] if dense else new)
+                return pool.at[page, off].set(new[rows, pos] if dense
+                                              else new)
             if stacked:
                 return pool.at[:, slot_ids].set(new)
             return pool.at[slot_ids].set(new)
@@ -271,8 +326,8 @@ def paged_programs(cfg, page_size: int, pages_per_slot: int) -> dict:
     # in models/transformer.py.
     def tick(params, pools, table, slot_ids, pos, tok):
         with jax.named_scope("page_gather"):
-            dense = gather(pools, table, slot_ids)
-        logits, new_caches = decode_step(params, dense, tok, pos, cfg)
+            caches = gather(pools, table, slot_ids)
+        logits, new_caches = decode_step(params, caches, tok, pos, cfg)
         with jax.named_scope("page_scatter"):
             pools = scatter_token(pools, new_caches, table, slot_ids, pos)
         # Greedy selection INSIDE the jitted program: the host only ever
@@ -355,7 +410,9 @@ class PagedRuntime:
     Built by :meth:`ServeEngine.start_paged`; the engine's ``admit`` /
     ``decode_tick`` / ``retire`` / ``free_pages`` delegate here.  Holds the
     :class:`PagePool`, the per-slot host decode state, and the compiled
-    gather→decode→scatter tick (one variant per power-of-two lane bucket).
+    decode tick (one variant per power-of-two lane bucket).  ``attn`` is
+    ``"paged"`` when every attention layer of the tick reads the pool in
+    place, else ``"gather"`` (recorded on ``engine.decode_tick``).
     Decode is greedy (the bitwise-oracle contract is argmax-per-row).
     """
 
@@ -387,8 +444,12 @@ class PagedRuntime:
 
         eng = self.engine
         cfg = eng.cfg
+        in_place = eng.mesh is None
         fns = paged_programs(cfg, self.pool.page_size,
-                             self.pool.pages_per_slot)
+                             self.pool.pages_per_slot, in_place=in_place)
+        # "paged" when every attention layer reads the pool in place.
+        direct = in_place_layers(cfg, self.pool.pools, in_place).values()
+        self.attn = "paged" if direct and all(direct) else "gather"
 
         if eng.mesh is not None:
             pool_sh = self._pool_shardings()
@@ -487,9 +548,9 @@ class PagedRuntime:
         return sorted(s for s, rec in self.slots.items() if rec.done)
 
     def decode_tick(self, sched=None):
-        """One decode step for every active slot: gather pages → dense view
-        → ``decode_step`` with per-row positions → scatter the written
-        token.  Returns {slot: newly generated token}.  Lane count pads to
+        """One decode step for every active slot: ``decode_step`` with
+        per-row positions over the pool (in place, or through a gathered
+        dense view), then the written token scattered to its page.  Returns {slot: newly generated token}.  Lane count pads to
         the next power of two (scratch-slot lanes), so admissions change the
         compiled variant at most ``log2(max_batch)+1`` times.
 
@@ -513,7 +574,7 @@ class PagedRuntime:
         written = sum(self.slots[s].write_pos // ps + 1 for s in active)
         with tr.span("engine.decode_tick", active=len(active),
                      fused=sched is not None, pages_reserved=reserved,
-                     pages_written=written):
+                     pages_written=written, attn=self.attn):
             return self._run_tick(active, sched, tr)
 
     def _run_tick(self, active: list[int], sched, tr):

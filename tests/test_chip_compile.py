@@ -110,3 +110,43 @@ def test_paged_tick_layer_compiles_for_v5e(chip, program):
     assert mem.argument_size_in_bytes >= weights
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < V5E_HBM_BYTES)
+
+
+@pytest.mark.parametrize("program", ["tick", "tick_sched_counted"])
+def test_paged_tick_reads_the_pool_in_place_on_v5e(chip, program,
+                                                   monkeypatch):
+    """Two deepseek-7b layers at published widths through the paged tick
+    with the TPU kernel path (what ``use_kernel`` picks on a chip): 4 lanes
+    of 1024 tokens.  The tick holds no dense (L, B, Smax, KV, hd) view: its
+    temporaries stay below one such view of K alone, where a gathered tick
+    needs two of K and V each."""
+    from repro.kernels import paged_attention
+
+    monkeypatch.setattr(paged_attention, "use_kernel", lambda: True)
+    cfg = get_config("deepseek-7b").with_(num_layers=2)
+    lanes, max_len, page = 4, 1024, 16
+    pp = max_len // page
+    params = _on(chip, param_specs(cfg))
+    pools = _on(chip, pool_shapes(cfg, lanes * pp, page, lanes, max_len))
+    i32 = jnp.int32
+    args = [params, pools, _sds(chip, (lanes, pp), i32),
+            _sds(chip, (lanes,), i32), _sds(chip, (lanes,), i32),
+            _sds(chip, (lanes, 1), i32)]
+    donate = (1,)
+    if program == "tick_sched_counted":
+        depth, pes = 8, 4
+        ctr = zero_counters()
+        args += [_sds(chip, (depth,), jnp.float32),
+                 _sds(chip, (depth, pes), jnp.float32),
+                 _sds(chip, (depth,), jnp.bool_),
+                 _sds(chip, (pes,), jnp.float32),
+                 _sds(chip, (pes,), jnp.bool_),
+                 _sds(chip, ctr.shape, ctr.dtype),
+                 _sds(chip, (pes,), jnp.bool_)]
+        donate = (1, 9, 11)
+    fn = paged_programs(cfg, page, pp)[program]
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    dense_k = (cfg.num_layers * lanes * max_len * cfg.num_kv_heads
+               * cfg.head_dim * 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < dense_k

@@ -7,6 +7,9 @@
   shared with the ``sched.*`` spans of the event that decided it;
 * ``tick.stage`` / ``tick.wait`` / ``tick.commit`` nest in their tick;
 * ``engine.admit`` says whether it admitted; page counts add up;
+* ``engine.decode_tick`` says whether attention read the page pool in
+  place (``attn``): every tick of an MHA model does, no tick of a windowed
+  or MLA model;
 * ``host.gc`` spans come from a hook that is removed afterwards;
 * tracing changes no token;
 * under ``jax.profiler`` the phases land on the host plane, and the
@@ -148,6 +151,30 @@ def test_pages_written_never_exceed_pages_reserved(traced):
         a = t.args
         assert 1 <= a["pages_written"] <= a["pages_reserved"]
         assert a["pages_reserved"] <= 8        # the pool's pages
+
+
+def test_every_tick_of_an_mha_model_attends_in_the_pool(traced):
+    tr, _ = traced
+    ticks = _spans(tr, "engine.decode_tick")
+    assert ticks and all(t.args["attn"] == "paged" for t in ticks)
+
+
+@pytest.mark.parametrize("arch", ["gemma2_9b", "deepseek_v2_236b"])
+def test_window_and_mla_models_gather_a_dense_view(arch):
+    """gemma2 (local windows, attention soft-cap) and deepseek-v2 (MLA)
+    keep the gathered view on every tick."""
+    from repro.configs import get_smoke_config
+
+    cfg = get_smoke_config(arch)
+    tr = Tracer()
+    eng = ServeEngine(cfg, init_params(jax.random.key(0), cfg), max_len=16,
+                      tracer=tr)
+    eng.start_paged(max_batch=2, page_size=8)
+    eng.admit(np.arange(1, 9, dtype=np.int32), 3)
+    while not eng.finished_slots():
+        eng.decode_tick()
+    ticks = _spans(tr, "engine.decode_tick")
+    assert len(ticks) == 2 and all(t.args["attn"] == "gather" for t in ticks)
 
 
 def test_admit_span_reports_a_refused_call(params):
