@@ -7,7 +7,7 @@ from repro.models.config import ModelConfig
 CONFIG = ModelConfig(
     name="deepseek-7b",
     num_layers=30, d_model=4096, num_heads=32, num_kv_heads=32,
-    d_ff=11008, vocab_size=102400,
+    d_ff=11008, vocab_size=102400, norm_eps=1e-6,
 )
 
 

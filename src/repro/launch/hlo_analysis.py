@@ -118,30 +118,21 @@ def analyze_hlo(hlo: str, unknown_trip: int = 1) -> dict:
                 if mb.group(1) in comps:
                     edges[name].append((mb.group(1), 1.0))
 
-    # propagate execution counts (call graph is a DAG)
+    # propagate execution counts (the call graph is a DAG): repeated
+    # relaxation, which settles within one pass per computation
     count: dict[str, float] = defaultdict(float)
     count[entry] = 1.0
-    order = [entry]
-    seen = {entry}
-    i = 0
-    while i < len(order):  # BFS in call order; DAG ⇒ revisit-safe accumulation
-        i += 1
-    # topological accumulation via repeated relaxation (small graphs)
     for _ in range(len(comps)):
-        changed = False
         new = defaultdict(float)
-        new[entry] = 1.0
         for caller, callees in edges.items():
             if count.get(caller, 0) <= 0:
                 continue
             for callee, mult in callees:
                 new[callee] += count[caller] * mult
         new[entry] = 1.0
-        if dict(new) != dict(count):
-            count = new
-            changed = True
-        if not changed:
+        if dict(new) == dict(count):
             break
+        count = new
 
     # definition map: op name → (dtype, dims); HLO op names are unique
     # module-wide in practice (suffix counters), so one global map suffices.

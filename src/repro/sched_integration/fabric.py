@@ -110,6 +110,17 @@ def default_backend() -> str:
     return "numpy" if jax.default_backend() == "cpu" else "jit"
 
 
+def _on_one_device(x):
+    """``x``, or where it lies on several devices an uncommitted copy on
+    the default device, through the host (a register file of a few floats).
+    A device-side copy would be committed and typed by the mesh it came
+    from, so the host-path kernel would trace one variant after a meshed
+    tick and another after a host-path decision."""
+    if len(x.sharding.device_set) > 1:
+        return jnp.asarray(np.asarray(x))
+    return x
+
+
 def pow2_bucket(n: int, min_bucket: int = 1) -> int:
     """Next power of two ≥ ``max(n, min_bucket, 1)``.
 
@@ -735,7 +746,12 @@ class MappingFabric:
         registers (and, for the fused backend, the device mask register)
         through when enabled."""
         if self.backend == "fused":
+            # A meshed decode tick hands the registers back replicated over
+            # its devices; the host path's kernel is a Mosaic call, which
+            # cannot be partitioned, so it takes them on one device.
+            av_in = _on_one_device(av_in)
             if self._device_counters:
+                self._counters = _on_one_device(self._counters)
                 res, self._counters = fn(a_p, ex_p, av_in, valid,
                                          self._mask_dev, self._counters,
                                          self._p_valid)

@@ -322,9 +322,10 @@ class ServeEngine:
 
         With a tracer attached, a tick that runs at least one lane records
         an ``engine.decode_tick`` span (``active``, ``fused``,
-        ``pages_reserved``, ``pages_written``) around ``tick.stage``,
-        ``tick.wait`` and ``tick.commit`` phases; an empty tick records
-        nothing."""
+        ``pages_reserved``, ``pages_written``, ``attn``; on a meshed engine
+        also ``chips``, ``lanes``, ``view_bytes`` and ``exchange_bytes``)
+        around ``tick.stage``, ``tick.wait`` and ``tick.commit`` phases; an
+        empty tick records nothing."""
         return self._require_paged().decode_tick(sched)
 
     def finished_slots(self) -> list[int]:

@@ -67,6 +67,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.tree_util import tree_map_with_path
 
 from repro.kernels.fused_decision import decision_ref, pack_tick_outputs
@@ -248,6 +249,39 @@ def in_place_layers(cfg, pools, in_place: bool = True) -> dict:
     return out
 
 
+def lane_view_bytes(pool: PagePool, in_place: bool = True) -> int:
+    """Per device, the bytes of one lane's dense K/V view over the layers
+    the tick gathers (:func:`in_place_layers`): a ``B``-lane tick builds
+    ``B`` times this, and an in-place tick none.  Read from each pool
+    leaf's shard on one device, so a view sharded over KV heads counts
+    that device's heads only."""
+    direct = in_place_layers(pool.cfg, pool.pools, in_place)
+    subs = [(("first", i), t) for i, t in enumerate(pool.pools["first"])]
+    subs += [(("stages", n), t) for n, t in pool.pools["stages"].items()]
+    total = 0
+    for where, tree in subs:
+        if direct.get(where):
+            continue
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+            if _leaf_kind(path)[0]:
+                shard = math.prod(leaf.sharding.shard_shape(leaf.shape))
+                total += (shard * leaf.dtype.itemsize
+                          // (pool.num_pages + 1) * pool.pages_per_slot)
+    return total
+
+
+def compiled_wire_bytes(fn, args) -> int:
+    """Per device, the collective wire bytes of the executable of the
+    jitted ``fn`` for ``args``, trip-weighted over its loops.  Called after
+    ``fn(*args)``: the lowering finds the executable that call built (the
+    donated buffers of ``args`` need only their shapes), so nothing
+    compiles again."""
+    from repro.launch.hlo_analysis import analyze_hlo
+
+    hlo = fn.lower(*args).compile().as_text()
+    return int(analyze_hlo(hlo)["total_wire_bytes_per_device"])
+
+
 def paged_programs(cfg, page_size: int, pages_per_slot: int,
                    in_place: bool = True) -> dict:
     """The paged runtime's device programs as plain (unjitted) functions.
@@ -412,7 +446,11 @@ class PagedRuntime:
     :class:`PagePool`, the per-slot host decode state, and the compiled
     decode tick (one variant per power-of-two lane bucket).  ``attn`` is
     ``"paged"`` when every attention layer of the tick reads the pool in
-    place, else ``"gather"`` (recorded on ``engine.decode_tick``).
+    place, else ``"gather"`` (recorded on ``engine.decode_tick``).  On a
+    meshed engine each tick also records ``chips``, ``lanes`` (its lane
+    bucket), ``view_bytes`` (per chip, the dense K/V view it gathers) and
+    ``exchange_bytes`` (per chip, the collective wire bytes of its compiled
+    program).
     Decode is greedy (the bitwise-oracle contract is argmax-per-row).
     """
 
@@ -455,25 +493,33 @@ class PagedRuntime:
             pool_sh = self._pool_shardings()
             specs = replica_pspecs(cfg, eng.axes, fsdp=eng.fsdp)
             p_sh = named(eng.mesh, specs["params"])
+            # Every operand but weights and pool, and every output but the
+            # pool, is replicated: the scheduler's registers leave a tick
+            # as the next takes them, so re-placing them (``_run_tick``)
+            # costs nothing unless a host-path decision moved them.
+            rep = self._replicated = NamedSharding(eng.mesh, P())
             with eng._ctx():
                 self.pool.pools = reshard_tree(self.pool.pools, pool_sh)
-            self._tick = jax.jit(
+            self.chips = eng.mesh.devices.size
+            self._lane_view_bytes = lane_view_bytes(self.pool, in_place)
+            self.exchange_bytes = 0
+            self._tick = self._exchange_counted(jax.jit(
                 fns["tick"],
-                in_shardings=(p_sh, pool_sh, None, None, None, None),
-                out_shardings=(None, pool_sh), donate_argnums=(1,))
-            # Scheduler operands replicate; the fabric's T_avail register
-            # file (arg 9) and counter file (arg 11) are donated so the
-            # registers stay device-resident across ticks.
-            self._tick_sched = jax.jit(
+                in_shardings=(p_sh, pool_sh) + (rep,) * 4,
+                out_shardings=(rep, pool_sh), donate_argnums=(1,)))
+            # The fabric's T_avail register file (arg 9) and counter file
+            # (arg 11) are donated so the registers stay device-resident
+            # across ticks.
+            self._tick_sched = self._exchange_counted(jax.jit(
                 fns["tick_sched"],
-                in_shardings=(p_sh, pool_sh) + (None,) * 9,
-                out_shardings=(None, pool_sh, None),
-                donate_argnums=(1, 9))
-            self._tick_sched_counted = jax.jit(
+                in_shardings=(p_sh, pool_sh) + (rep,) * 9,
+                out_shardings=(rep, pool_sh, rep),
+                donate_argnums=(1, 9)))
+            self._tick_sched_counted = self._exchange_counted(jax.jit(
                 fns["tick_sched_counted"],
-                in_shardings=(p_sh, pool_sh) + (None,) * 11,
-                out_shardings=(None, pool_sh, None, None),
-                donate_argnums=(1, 9, 11))
+                in_shardings=(p_sh, pool_sh) + (rep,) * 11,
+                out_shardings=(rep, pool_sh, rep, rep),
+                donate_argnums=(1, 9, 11)))
             self._admit_scatter = jax.jit(
                 fns["admit_scatter"],
                 in_shardings=(pool_sh, eng._cache_sh, None, None),
@@ -483,6 +529,7 @@ class PagedRuntime:
                 in_shardings=(pool_sh, None, None, None),
                 out_shardings=pool_sh, donate_argnums=(0,))
         else:
+            self.chips = None
             self._tick = jax.jit(fns["tick"], donate_argnums=(1,))
             self._tick_sched = jax.jit(fns["tick_sched"],
                                        donate_argnums=(1, 9))
@@ -494,6 +541,23 @@ class PagedRuntime:
                                             donate_argnums=(0,))
         # Scratch-page id, exposed for tests/introspection.
         self.scratch_page = self.pool.scratch_page
+
+    def _exchange_counted(self, fn):
+        """A meshed tick program that reads, once per lane bucket, the
+        collective wire bytes per chip of its compiled executable,
+        trip-weighted over the layer scan (:func:`compiled_wire_bytes`),
+        into ``exchange_bytes``."""
+        wire = {}
+
+        def call(*args):
+            out = fn(*args)
+            B = args[2].shape[0]
+            if B not in wire:
+                wire[B] = compiled_wire_bytes(fn, args)
+            self.exchange_bytes = wire[B]
+            return out
+
+        return call
 
     def rebind(self) -> None:
         """Re-place the pools and rebuild the tick after an engine reshard.
@@ -574,8 +638,14 @@ class PagedRuntime:
         written = sum(self.slots[s].write_pos // ps + 1 for s in active)
         with tr.span("engine.decode_tick", active=len(active),
                      fused=sched is not None, pages_reserved=reserved,
-                     pages_written=written, attn=self.attn):
-            return self._run_tick(active, sched, tr)
+                     pages_written=written, attn=self.attn) as span:
+            out = self._run_tick(active, sched, tr)
+            if self.chips is not None:
+                B = pow2_bucket(len(active), 1)
+                span.set(chips=self.chips, lanes=B,
+                         view_bytes=B * self._lane_view_bytes,
+                         exchange_bytes=self.exchange_bytes)
+            return out
 
     def _run_tick(self, active: list[int], sched, tr):
         """:meth:`decode_tick` over the live slots ``active``; ``tr`` (a
@@ -612,6 +682,12 @@ class PagedRuntime:
                 n = len(avg)
                 (a_p, ex_p, valid, avail, mask,
                  counters, p_valid) = fab.tick_decision_inputs(avg, exec_times)
+                if self.chips is not None:
+                    # A host-path decision leaves the registers on one
+                    # device; back on the mesh they meet the executable
+                    # their bucket compiled, not a second variant.
+                    avail, counters = jax.device_put((avail, counters),
+                                                     self._replicated)
                 stage.__exit__(None, None, None)
                 if counters is None:
                     packed, self.pool.pools, new_avail = self._tick_sched(
