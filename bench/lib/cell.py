@@ -154,7 +154,7 @@ def run(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
     setup_s = run_.t_base - process_start
     win = run_.window_reqs
 
-    e2e = timings.end_to_end(run_.reqs, win, run_.w0, run_.w1, setup_s)
+    e2e = timings.end_to_end(win, run_.w0, run_.w1, setup_s)
     say("end to end: " + json.dumps(e2e))
     say("generator lateness ms: " + json.dumps(timings.lateness_ms(win)))
     say("compiles after set-up: " + json.dumps(

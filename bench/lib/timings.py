@@ -2,6 +2,14 @@
 
 Every request due in the window counts, and every gap between two of its
 consecutive output tokens; no metric is a median of chunks.
+
+``out_tok_s`` counts the window's own requests' tokens delivered inside the
+window [w0, w1), over its length.  Every such request is due at or after
+w0, so a token served sooner can only move into the window, never out of
+it: the count never falls as the system gets faster.  Below the knee it
+reads the offered tokens less those still to come at w1.  Lead-in
+requests' tokens never count: a slower system would serve more of them
+inside the window and read higher.
 """
 
 from __future__ import annotations
@@ -21,20 +29,19 @@ def gaps_s(reqs) -> np.ndarray:
 
 
 def tokens_in(reqs, w0: float, w1: float) -> int:
-    """Output tokens of any request delivered inside [w0, w1)."""
+    """Output tokens of ``reqs`` delivered inside [w0, w1)."""
     return int(sum(((np.asarray(r.token_t) >= w0)
                     & (np.asarray(r.token_t) < w1)).sum() for r in reqs))
 
 
-def end_to_end(reqs, window_reqs, w0: float, w1: float,
-               setup_s: float) -> dict:
+def end_to_end(window_reqs, w0: float, w1: float, setup_s: float) -> dict:
     """The five end-to-end metrics, by name."""
     g = gaps_s(window_reqs)
     return {
         "ttft_p90_ms": 1e3 * float(np.percentile(ttfts_s(window_reqs), 90)),
         "itl_mean_ms": 1e3 * float(g.sum() / len(g)),
         "itl_p99_ms": 1e3 * float(np.percentile(g, 99)),
-        "out_tok_s": tokens_in(reqs, w0, w1) / (w1 - w0),
+        "out_tok_s": tokens_in(window_reqs, w0, w1) / (w1 - w0),
         "setup_s": float(setup_s),
     }
 

@@ -35,14 +35,25 @@ def test_sound_run_is_correct():
 
 
 def _stale_state(monkeypatch):
-    """The decode step hands back the cache it was given: the tick's state
-    never changes."""
-    import repro.models.model as model
+    """The decode tick hands back the page pool it was given: the tick's
+    state never changes."""
+    import repro.serve.paging as paging
 
-    orig = model.decode_step
-    monkeypatch.setattr(model, "decode_step",
-                        lambda p, c, t, pos, cfg: (orig(p, c, t, pos, cfg)[0],
-                                                   c))
+    orig = paging.paged_programs
+
+    def keep_pools(fn):
+        def call(params, pools, *rest):
+            out = fn(params, pools, *rest)
+            return (out[0], pools) + tuple(out[2:])
+        return call
+
+    def programs(*a, **kw):
+        fns = orig(*a, **kw)
+        for name in ("tick", "tick_sched", "tick_sched_counted"):
+            fns[name] = keep_pools(fns[name])
+        return fns
+
+    monkeypatch.setattr(paging, "paged_programs", programs)
 
 
 def _half_batch(monkeypatch):

@@ -74,15 +74,16 @@ def test_metric_arithmetic_on_synthetic_timestamps():
             _served(10.5, [11.0, 11.1], 1),
             _served(9.0, [9.5, 10.05, 12.5], 2, window=False)]
     win = [r for r in reqs if r.in_window]
-    m = timings.end_to_end(reqs, win, 10.0, 12.0, setup_s=42.0)
+    m = timings.end_to_end(win, 10.0, 12.0, setup_s=42.0)
     # ttft: 0.1 and 0.5; p90 by linear interpolation: 0.1 + 0.9 * 0.4.
     assert m["ttft_p90_ms"] == pytest.approx(460.0)
     # gaps 0.1, 0.2, 0.1 of the window's requests only.
     assert m["itl_mean_ms"] == pytest.approx(400.0 / 3)
     assert m["itl_p99_ms"] == pytest.approx(1e3 * np.percentile(
         [0.1, 0.2, 0.1], 99))
-    # tokens inside [10, 12): 3 + 2 + the lead-in request's 10.05.
-    assert m["out_tok_s"] == pytest.approx(6 / 2.0)
+    # the window's requests' tokens inside [10, 12): 3 + 2; the lead-in
+    # request's token at 10.05 does not count.
+    assert m["out_tok_s"] == pytest.approx(5 / 2.0)
     assert m["setup_s"] == 42.0
 
 

@@ -5,7 +5,10 @@
         --rates 1.5,2,2.5,3 --seconds 20 --seed 5
 
 Builds and warms the cell once, then serves each rate's schedule in turn
-and prints one JSON line per rate: the end-to-end metrics, and the backlog
+and prints one JSON line per rate: the end-to-end metrics, every request's
+tokens delivered in the window per second (``delivered_tok_s``: lead-in
+requests' too, so above the knee it reads the tick's ceiling, where
+``out_tok_s`` counts only the window's own requests), and the backlog
 (requests due but not yet admitted) at the window's start and end with the
 mean queue wait of the window's first and second halves.  The knee is the
 highest rate at which the backlog does not grow over the window.
@@ -45,7 +48,9 @@ def main(argv=None) -> int:
         first = [w for d, w in waits if d < mid]
         second = [w for d, w in waits if d >= mid]
         row = {"rate_per_s": rate,
-               **timings.end_to_end(run.reqs, win, run.w0, run.w1, 0.0),
+               **timings.end_to_end(win, run.w0, run.w1, 0.0),
+               "delivered_tok_s": timings.tokens_in(run.reqs, run.w0, run.w1)
+               / (run.w1 - run.w0),
                "backlog_start": backlog(run.reqs, run.w0),
                "backlog_end": backlog(run.reqs, run.w1),
                "wait_ms_first_half": 1e3 * float(np.mean(first)),
